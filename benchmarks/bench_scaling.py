@@ -1,6 +1,5 @@
 """SPMD scaling table (DESIGN.md §4): tokens/s and per-device KV bytes vs
-mesh size on a forced multi-device CPU topology (real accelerators when
-present). Each row serves the SAME mixed-length workload through
+mesh size. Each row serves the SAME mixed-length workload through
 serve.build_lanes/run_lanes:
 
   * 1x1 — single-device baseline (mesh plumbing off)
@@ -11,35 +10,35 @@ serve.build_lanes/run_lanes:
 Reported per row: aggregate tokens/s, per-lane tokens/s, per-device
 peak reserved/active KV bytes (the 1xM rows shrink these by M), TP degree.
 
-When the calling process holds < 4 devices (benchmarks/run.py runs on the
-default topology), the module re-execs itself in a child with
-``--xla_force_host_platform_device_count=4`` and relays the rows — so the
-aggregated --json artifact always includes the scaling table.
+Every mesh runs in this process, over the devices it holds (one process
+per chip); a mesh wider than that is left out of the table. CPU runs get
+four virtual devices by setting XLA_FLAGS before jax starts:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         PYTHONPATH=src python -m benchmarks.bench_scaling --json scaling.json
 """
 import argparse
-import json
-import os
-import subprocess
-import sys
 
 MESHES = ("1x1", "1x2", "2x1", "2x2")
-NEED_DEVICES = 4
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run_inline():
-    import jax  # noqa: F401  (device count already forced by the caller)
+def run():
+    """Harness entry (benchmarks/run.py): the meshes the process's devices
+    can hold."""
+    import jax
     from benchmarks.common import record_audit, row, smoke_scale
     from repro.data import traces
+    from repro.launch.mesh import parse_mesh_spec
     from repro.launch.serve import build_lanes, run_lanes
 
     scale = smoke_scale()
     n_req = max(8, int(24 * scale))
+    n_dev = len(jax.devices())
     rows = []
     for spec in MESHES:
+        d, m = parse_mesh_spec(spec)
+        if d * m > n_dev:
+            continue
         engines = build_lanes("qwen2.5-32b", "paged_merge", 8, 128, spec,
                               pool_budget_frac=0.5, pipeline_depth=1,
                               prefill_chunk=16)
@@ -62,50 +61,14 @@ def _run_inline():
     return rows
 
 
-def run():
-    """Harness entry (benchmarks/run.py). Re-exec with a forced device count
-    when the current process can't host the meshes."""
-    import jax
-    if len(jax.devices()) >= NEED_DEVICES:
-        return _run_inline()
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count="
-                          f"{NEED_DEVICES}").strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (os.path.join(ROOT, "src"), env.get("PYTHONPATH")) if p)
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_scaling", "--emit-rows"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError(f"bench_scaling child failed:\n{out.stderr[-2000:]}")
-    payload = json.loads(out.stdout.strip().splitlines()[-1])
-    from benchmarks.common import record_audit
-    for name, audit in payload["audits"].items():
-        record_audit(name, audit)
-    return [(r[0], r[1], r[2]) for r in payload["rows"]]
-
-
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
-    ap.add_argument("--emit-rows", action="store_true",
-                    help="print rows+audits as one JSON line (child mode)")
     ap.add_argument("--json", default=None,
                     help="write rows as a JSON summary")
     args = ap.parse_args()
 
-    if "xla_force_host_platform_device_count" not in \
-            os.environ.get("XLA_FLAGS", ""):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={NEED_DEVICES}").strip()
-
-    rows = _run_inline()
-    from benchmarks.common import collected_audits, print_rows, write_json
-    if args.emit_rows:
-        print(json.dumps({"rows": rows, "audits": collected_audits()},
-                         default=float))
-    else:
-        print_rows(rows)
-        if args.json:
-            write_json(rows, args.json)
+    rows = run()
+    from benchmarks.common import print_rows, write_json
+    print_rows(rows)
+    if args.json:
+        write_json(rows, args.json)

@@ -73,6 +73,8 @@ def main() -> None:
                          "(applied pre-jax-import by the bootstrap above)")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     failed = []
     agg = {}

@@ -74,6 +74,7 @@ from repro.core.prefix_cache import PrefixCache
 from repro.core.sampling import make_sampler, slot_keys
 from repro.core.scheduler import Request, Scheduler
 from repro.core.transport import MergeStagedTransport, StagedDescriptor, merge_runs
+from repro.kernels import ops
 from repro.models import registry
 from repro.serving.api import AuditReport
 
@@ -380,8 +381,9 @@ class KVRMEngine:
         def _step_core(params, host_tokens, feed_sampled, rids, prev_nxt,
                        pools, descr):
             tokens = jnp.where(feed_sampled > 0, prev_nxt, host_tokens)
-            logits, pools, fu = registry.decode_step(params, cfg_dec, tokens,
-                                                     pools, descr)
+            with ops.kernel_mesh(self.mesh):
+                logits, pools, fu = registry.decode_step(params, cfg_dec,
+                                                         tokens, pools, descr)
             if self._sampled:
                 keys = slot_keys(base_key, rids, descr.seq_lens)
                 nxt = sampler(keys, logits)
@@ -420,7 +422,7 @@ class KVRMEngine:
                 in_shardings=(self._param_sh, R, R, PS),
                 out_shardings=(R, PS, R, R)))
             self._step_fn = jax.jit(_step_flat, donate_argnums=(3,), **kw)
-        self._compiles = 0
+        self._step_sig = None
         self.debug_logits: List[np.ndarray] = []
 
         # --- chunked prefill executor (second fixed-shape compilation) ---
@@ -433,7 +435,9 @@ class KVRMEngine:
             C = self.chunk
             def _chunk_step(params, pools, cflat):
                 cdescr = unflatten_chunk_descriptor(cflat, B, C, NB)
-                return registry.prefill_chunk(params, cfg_dec, pools, cdescr)
+                with ops.kernel_mesh(self.mesh):
+                    return registry.prefill_chunk(params, cfg_dec, pools,
+                                                  cdescr)
             ckw = ({} if self.mesh is None else dict(
                 in_shardings=(self._param_sh, PS, R), out_shardings=PS))
             self._chunk_fn = jax.jit(_chunk_step, donate_argnums=(1,), **ckw)
@@ -1042,7 +1046,10 @@ class KVRMEngine:
                                 + tuple(arr.shape[2:]),
                                 self._host_kv[k].dtype)
                 data[:, :n] = np.moveaxis(self._host_kv[k][host_slots], 0, 1)
-            self.pools[k] = self._swap_scatter_fn(arr, jidx, jnp.asarray(data))
+            # a snapshot of the staging buffer: the scatter may still be
+            # queued when this buffer is reused two transfers later
+            self.pools[k] = self._swap_scatter_fn(arr, jidx,
+                                                  jnp.asarray(data.copy()))
         self._stage_sel ^= 1
         self.swap_stall_s += time.perf_counter() - t0
 
@@ -1346,7 +1353,7 @@ class KVRMEngine:
         if any_chunk:
             td = time.perf_counter()
             self.pools = self._chunk_fn(self.params, self.pools,
-                                        jnp.asarray(self._cflat))
+                                        jnp.asarray(self._cflat.copy()))
             # dispatch can block on the runtime's in-flight queue while the
             # PREVIOUS step still executes — that wait is device occupancy,
             # not host control work; the pipelined path subtracts it from
@@ -1476,7 +1483,7 @@ class KVRMEngine:
 
         # ---- device: one engine call, fixed shapes
         self.transport.note_dispatch_overlap()
-        nxt, self.pools, fu, lg = self._step_fn(
+        nxt, self.pools, fu, lg = self._dispatch_step(
             self.params, jnp.asarray(tokens), self._zero_feed,
             jnp.asarray(self._rid_buf), self._prev_nxt, self.pools, jdescr)
         self._prev_nxt = nxt
@@ -1672,13 +1679,16 @@ class KVRMEngine:
             d.epoch[...] = self.steps_run + 1
         m.frame_commit = time.perf_counter() - tf0
 
-        jflat = jnp.asarray(self._flat)      # ONE host->device transfer
+        # ONE host->device transfer, of a snapshot: jax may read (or, on
+        # the CPU, alias) a host array after dispatch returns, and _flat is
+        # rewritten in place for the next step while this one is in flight
+        jflat = jnp.asarray(self._flat.copy())
         # chunk-dispatch queue wait is device occupancy, not control work
         m.host = max(0.0, time.perf_counter() - t0 - self._chunk_wait)
 
         # ---- device: dispatch step t (async), keep host moving
         self.transport.note_dispatch_overlap()
-        nxt, self.pools, fu, lg = self._step_fn(
+        nxt, self.pools, fu, lg = self._dispatch_step(
             self.params, jflat, self._prev_nxt, self.pools)
         self._prev_nxt = nxt
 
@@ -1712,6 +1722,25 @@ class KVRMEngine:
         self.peak_active_kv = max(self.peak_active_kv, self.active_kv_bytes())
         self.metrics.append(m)
         return m
+
+    # ------------------------------------------------------------------
+    def _dispatch_step(self, *args):
+        """Run the decode executor; the first call also records its
+        operands' shapes and placements for :meth:`decode_executor_hlo`."""
+        if self._step_sig is None:
+            self._step_sig = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=a.sharding), args)
+        return self._step_fn(*args)
+
+    def decode_executor_hlo(self) -> str:
+        """Compiled HLO text of the decode executor at its fixed shapes (on
+        TPU the attention kernel shows as ``tpu_custom_call``). Compiles
+        ahead of time; the executor's own cache and the audited compile
+        count are untouched. Needs one dispatched step."""
+        if self._step_sig is None:
+            raise RuntimeError("no decode step dispatched yet")
+        return self._step_fn.lower(*self._step_sig).compile().as_text()
 
     # ------------------------------------------------------------------
     def _readback(self, rec: dict) -> None:

@@ -30,7 +30,7 @@ def _sum_kernel(chunk_tbl_ref, meta_ref, pool_ref, o_ref, acc_ref,
     n_tok = meta_ref[b, 0]
     gate = meta_ref[b, 1]
 
-    blk = pool_ref[0].astype(jnp.float32).reshape(bt, width)   # (BT, width)
+    blk = pool_ref[0].astype(jnp.float32)                      # (BT, width)
     pos = i * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
     m = ((pos < n_tok) & (gate > 0)).astype(jnp.float32)
     acc_ref[...] += (blk * m).sum(axis=0, keepdims=True)
@@ -39,7 +39,7 @@ def _sum_kernel(chunk_tbl_ref, meta_ref, pool_ref, o_ref, acc_ref,
     def _fin():
         denom = jnp.maximum(n_tok, 1).astype(jnp.float32)
         out = acc_ref[...] / denom
-        o_ref[...] = jnp.where(gate > 0, out, 0.0).astype(o_ref.dtype)
+        o_ref[0] = jnp.where(gate > 0, out, 0.0).astype(o_ref.dtype)
 
 
 def farview_summarize_pallas(pool, chunk_blocks, n_tokens, do_summarize,
@@ -70,11 +70,15 @@ def _farview_summarize_impl(pool, chunk_blocks, n_tokens, do_summarize,
         grid=(B, CB),
         in_specs=[pl.BlockSpec((1, BT, width),
                                lambda b, i, tbl, meta: (tbl[b, i], 0, 0))],
-        out_specs=pl.BlockSpec((1, width), lambda b, i, tbl, meta: (b, 0)),
+        # (B, 1, width) with (1, 1, width) blocks: the block's last two dims
+        # are the array's own, as the TPU tiling requires
+        out_specs=pl.BlockSpec((1, 1, width),
+                               lambda b, i, tbl, meta: (b, 0, 0)),
         scratch_shapes=[pltpu.VMEM((1, width), jnp.float32)],
     )
     out = pl.pallas_call(kernel, grid_spec=gs,
-                         out_shape=jax.ShapeDtypeStruct((B, width), pool.dtype),
-                         interpret=interpret,
+                         out_shape=jax.ShapeDtypeStruct((B, 1, width),
+                                                        pool.dtype),
+                         interpret=interpret, name="farview_summarize",
                          )(chunk_blocks.astype(jnp.int32), meta, pool2)
     return out.reshape((B,) + payload)

@@ -1,72 +1,17 @@
 """Backend-aware kernel runtime policy (shared by all Pallas entry points).
 
-Two decisions every kernel wrapper needs, made once here:
-
-* ``resolve_interpret`` — whether ``pl.pallas_call`` should run in interpret
-  mode. Historically every entry point defaulted ``interpret=True`` and every
-  non-CPU caller had to remember to flip it; now the default (``None``)
-  resolves from the active jax backend: CPU -> interpret (there is no Mosaic
-  lowering to run), TPU/GPU -> compiled. An explicit bool always wins, and
-  ``REPRO_PALLAS_INTERPRET=0/1`` force-overrides for debugging a compiled
-  backend with the interpreter.
-
-* ``interpret_dma_supported`` — whether this jax's interpret mode implements
-  the ``pltpu.make_async_copy`` / DMA-semaphore primitives the
-  double-buffered decode path uses. Probed once with a tiny pallas_call and
-  cached; the double-buffered kernel falls back to direct ANY-space reads
-  (same buffering structure, no semaphores) when the probe fails, so the CPU
-  suite still exercises the staging logic on older jax.
+``resolve_interpret`` decides whether ``pl.pallas_call`` runs in interpret
+mode: exactly when the active jax backend is the CPU, which has no Mosaic
+lowering to run. An explicit bool wins (tests and the described-topology
+compiles pass ``False``).
 """
 from __future__ import annotations
 
-import functools
-import os
-
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
 def resolve_interpret(interpret=None) -> bool:
-    """Resolve the interpret flag for a Pallas kernel launch.
-
-    Explicit ``True``/``False`` is honored as-is; ``None`` (the new entry
-    point default) means "interpret iff the backend has no kernel compiler"
-    — i.e. CPU. ``REPRO_PALLAS_INTERPRET`` overrides the backend resolution
-    (but not an explicit argument).
-    """
+    """``None`` means interpret iff the default backend is the CPU."""
     if interpret is not None:
         return bool(interpret)
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None and env != "":
-        return env not in ("0", "false", "False")
     return jax.default_backend() == "cpu"
-
-
-@functools.lru_cache(maxsize=None)
-def interpret_dma_supported() -> bool:
-    """True iff interpret mode runs pltpu async-copy + DMA semaphores.
-
-    Cached module-wide; the probe is a one-off ~ms interpret launch on
-    concrete inputs (safe to call during tracing — concrete-array pallas
-    execution is eager, never staged into an ambient trace).
-    """
-    try:
-        def _k(x_ref, o_ref, buf, sem):
-            pltpu.make_async_copy(x_ref.at[0], buf.at[0], sem.at[0]).start()
-            pltpu.make_async_copy(x_ref.at[0], buf.at[0], sem.at[0]).wait()
-            o_ref[...] = buf[0]
-
-        out = pl.pallas_call(
-            _k,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-            out_specs=pl.BlockSpec((8,), lambda: (0,)),
-            out_shape=jax.ShapeDtypeStruct((8,), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((1, 8), jnp.float32),
-                            pltpu.SemaphoreType.DMA((1,))],
-            interpret=True,
-        )(jnp.arange(8, dtype=jnp.float32)[None, :])
-        return bool(jax.block_until_ready(out)[7] == 7.0)
-    except Exception:
-        return False

@@ -342,8 +342,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
             t_compile = time.time() - t0 - t_lower
         ma = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # jax 0.4.x: list of dicts
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         vis = (serving_plan(cfg, shape)["near_window"]
                if shape.kind == "decode" else None)

@@ -8,10 +8,8 @@ Engine     : (data=d, model=m) from ``--mesh dxm`` (launch/serve.py); each
              data row is one replicated engine lane, the model axis carries
              tensor-parallel decode (DESIGN.md §4).
 
-Version compat: ``jax.sharding.AxisType`` / ``axis_types=`` and
-``jax.set_mesh`` only exist on newer jax; this container pins jax 0.4.37.
-All mesh construction and mesh-context entry goes through the helpers below
-so the rest of the repo stays version-agnostic.
+All mesh construction and mesh-context entry goes through the helpers
+below.
 """
 from __future__ import annotations
 
@@ -19,34 +17,19 @@ from contextlib import contextmanager
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit/auto axis types
-    from jax.sharding import AxisType
-    _AXIS_TYPES = True
-except ImportError:  # jax 0.4.x: all axes behave as Auto
-    AxisType = None
-    _AXIS_TYPES = False
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh with Auto axis types where the API supports them."""
-    if _AXIS_TYPES:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 @contextmanager
 def mesh_context(mesh: Mesh):
-    """Enter a mesh scope: ``jax.set_mesh`` on new jax, ``with mesh:`` on
-    0.4.x (both make the mesh ambient for bare-PartitionSpec constraints)."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """Make ``mesh`` ambient for bare-PartitionSpec constraints."""
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -43,27 +43,25 @@ of the above. ``--kv-dtype bf16`` (default) is bitwise-identical to seed.
 Every flag and every ``audit()`` counter is tabulated with the invariant
 it witnesses in docs/OPERATIONS.md.
 
+Published widths: ``--layers N`` serves the arch's full
+published config cut to N layers instead of its reduced CPU preset — on a
+chip. Multi-device meshes on the CPU need virtual devices set before jax
+starts:
+
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         PYTHONPATH=src python -m repro.launch.serve --mesh 2x2
-    (when launched as __main__ the flag is set automatically for CPU runs)
 """
 from __future__ import annotations
 
-# --mesh / --xla-profile bootstrap: the forced host-device count and the
-# tuned XLA flag profile must be set BEFORE jax initializes, which is
-# before this module's own jax import when run as a script. Only touches
-# CPU runs that didn't set a device count themselves.
-import os
+# --xla-profile bootstrap: the tuned XLA flag profile must be set BEFORE
+# jax initializes, which is before this module's own jax import when run
+# as a script.
 import sys
 
 if __name__ == "__main__":
-    _spec = _prof = None
+    _prof = None
     for _i, _a in enumerate(sys.argv):
-        if _a == "--mesh" and _i + 1 < len(sys.argv):
-            _spec = sys.argv[_i + 1]
-        elif _a.startswith("--mesh="):
-            _spec = _a.split("=", 1)[1]
-        elif _a == "--xla-profile" and _i + 1 < len(sys.argv):
+        if _a == "--xla-profile" and _i + 1 < len(sys.argv):
             _prof = sys.argv[_i + 1]
         elif _a.startswith("--xla-profile="):
             _prof = _a.split("=", 1)[1]
@@ -71,17 +69,6 @@ if __name__ == "__main__":
         from repro.launch import xla_flags as _xf
         if _prof in _xf.PROFILES:      # unknown name -> argparse errors later
             _xf.apply_profile(_prof)
-    if _spec is not None:
-        try:
-            _d, _m = (int(x) for x in _spec.lower().split("x"))
-            if "xla_force_host_platform_device_count" not in \
-                    os.environ.get("XLA_FLAGS", ""):
-                os.environ["XLA_FLAGS"] = (
-                    os.environ.get("XLA_FLAGS", "")
-                    + f" --xla_force_host_platform_device_count={_d * _m}"
-                ).strip()
-        except ValueError:
-            pass
 
 import argparse
 import json
@@ -89,10 +76,12 @@ import time
 
 import numpy as np
 
+from repro.configs import get_config
 from repro.configs.base import SamplingConfig
 from repro.core.engine import KVRMEngine
 from repro.data import traces
 from repro.launch import xla_flags
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_engine(arch: str, mode: str, batch: int, max_seq: int,
@@ -235,6 +224,10 @@ def build_arg_parser() -> argparse.ArgumentParser:
     docs/OPERATIONS.md."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-32b")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="serve the arch's published config cut to this "
+                         "many layers (a chip run); 0 = the reduced preset "
+                         "that CPU runs use")
     ap.add_argument("--mode", default="paged_merge",
                     choices=["arena", "paged", "paged_merge", "full"])
     ap.add_argument("--workload", default="mixed",
@@ -353,6 +346,7 @@ def build_arg_parser() -> argparse.ArgumentParser:
 def main(argv=None):
     ap = build_arg_parser()
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if (args.kv_oversubscribe > 1.0 or args.host_pool_blocks > 0) \
             and args.mesh not in ("1x1", "1X1"):
@@ -380,7 +374,9 @@ def main(argv=None):
                          top_k=sampling.top_k, top_p=sampling.top_p,
                          sample_seed=sampling.seed)
 
-    engines = build_lanes(args.arch, args.mode, args.batch, args.max_seq,
+    model = (get_config(args.arch).replace(n_layers=args.layers)
+             if args.layers else args.arch)
+    engines = build_lanes(model, args.mode, args.batch, args.max_seq,
                           args.mesh, pool_budget_frac=args.pool_budget,
                           **sample_kw,
                           kv_oversubscribe=args.kv_oversubscribe,

@@ -47,9 +47,11 @@ def dense(p, x):
     # decode (DESIGN.md §4) a contraction-sharded projection (wo, mlp down,
     # lm_head) becomes per-shard partial dots + one psum; keeping the partials
     # and the all-reduce in f32 makes the sharded result match the
-    # single-device result bitwise on every tested degree — a bf16-output dot
-    # would round each partial before a bf16 all-reduce and drift ~1e-2,
-    # flipping greedy argmax at bf16 logit ties.
+    # single-device result bitwise on the CPU at every tested degree — a
+    # bf16-output dot would round each partial before a bf16 all-reduce and
+    # drift ~1e-2, flipping greedy argmax at bf16 logit ties. The TPU
+    # compiler narrows these all-reduces to bf16 anyway (chip_smoke.py,
+    # TP_TOL), so on the chip TP matches 1x1 only to bf16 rounding.
     y = jax.lax.dot_general(x, p["w"], (((x.ndim - 1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32).astype(x.dtype)
     if "b" in p:

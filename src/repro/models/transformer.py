@@ -98,13 +98,6 @@ def prefill_chunk(params, cfg: ModelConfig, pools, descr):
     positions = descr.start_pos[:, None] + jnp.arange(C)[None]  # (B, C)
     quant = "k_scale" in pools                        # narrow KV tier (§10)
 
-    attend = jax.vmap(
-        lambda q, pk, pv, k, v, tbl, wb, sp, nv, ks, vs:
-        ops.chunked_prefill_attention(
-            q, pk, pv, k, v, tbl, wb, sp, nv, near_window=sv.near_window,
-            k_scale=ks, v_scale=vs, skip_extent=sv.skip_extent),
-        in_axes=(0, None, None, 0, 0, 0, 0, 0, 0, None, None))
-
     # Same read-only pool discipline as decode_step: each layer's chunk K/V
     # attends explicitly and is emitted as a delta, scattered once post-scan.
     def block(x, layer_xs):
@@ -115,8 +108,10 @@ def prefill_chunk(params, cfg: ModelConfig, pools, descr):
             psk = psv = None
         h = cm.rmsnorm(layer["ln1"], x, cfg.norm_eps)
         q, k, v = cm.gqa_qkv(layer["attn"], cfg, h, positions)
-        o = attend(q, pk, pv, k, v, descr.block_table, descr.window_base,
-                   descr.start_pos, descr.n_valid, psk, psv)  # (B, C, H, hd)
+        o = ops.chunked_prefill_attention(
+            q, pk, pv, k, v, descr.block_table, descr.window_base,
+            descr.start_pos, descr.n_valid, near_window=sv.near_window,
+            k_scale=psk, v_scale=psv, skip_extent=sv.skip_extent)  # (B,C,H,hd)
         x = x + cm.dense(layer["attn"]["wo"], o.reshape(B, C, -1))
         h = cm.rmsnorm(layer["ln2"], x, cfg.norm_eps)
         x = x + cm.mlp_apply(layer["mlp"], h, cfg.mlp_act)

@@ -36,14 +36,11 @@ KERNELS = (
 
 
 def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() across jax versions: dict, list-of-dict
-    (jax 0.4.x CPU), or unavailable."""
+    """compiled.cost_analysis() as a dict ({} where unavailable)."""
     try:
         cost = compiled.cost_analysis()
     except Exception:
         return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     return dict(cost or {})
 
 

@@ -14,17 +14,23 @@ over them with ``gateway=True``):
     ``prefix_cache=True`` for affinity routing, unlike sharded lanes);
   * default — one single-device engine, seed-exact.
 
-Params are cached per (arch, seed): ``init_params`` from the same
-PRNGKey is deterministic, so sharing the cache across engines keeps
-memory flat and every construction site bitwise-identical.
+``arch`` is an architecture id, which builds its reduced preset (the CPU
+default of tests and examples), or a :class:`ModelConfig`, which builds
+exactly that model — e.g. ``get_config(arch).replace(n_layers=4)`` at its
+published widths on a chip.
+
+Params are cached per (config, seed). They are initialized by one jitted
+program on the default device, so the f32 draws behind the bf16 weights
+stay inside the compiled init instead of materializing eagerly.
 """
 from __future__ import annotations
 
-from typing import List, Optional
+from functools import partial
+from typing import List, Optional, Union
 
 import jax
 
-from repro.configs import get_reduced
+from repro.configs import ModelConfig, get_reduced
 from repro.core.engine import EngineConfig, KVRMEngine
 from repro.launch import mesh as mesh_mod
 from repro.models import registry
@@ -32,15 +38,26 @@ from repro.models import registry
 _PARAM_CACHE = {}
 
 
-def cached_params(arch: str, seed: int = 0):
-    key = (arch, seed)
+def resolve_config(arch: Union[str, ModelConfig]) -> ModelConfig:
+    """An arch id's reduced preset, or the given config unchanged."""
+    return arch if isinstance(arch, ModelConfig) else get_reduced(arch)
+
+
+@partial(jax.jit, static_argnums=1)
+def _init_params(key, cfg: ModelConfig):
+    return registry.init_params(key, cfg)
+
+
+def cached_params(arch: Union[str, ModelConfig], seed: int = 0):
+    cfg = resolve_config(arch)
+    key = (cfg, seed)
     if key not in _PARAM_CACHE:
-        cfg = get_reduced(arch)
-        _PARAM_CACHE[key] = registry.init_params(jax.random.PRNGKey(seed), cfg)
+        _PARAM_CACHE[key] = _init_params(jax.random.PRNGKey(seed), cfg)
     return _PARAM_CACHE[key]
 
 
-def build(arch: str = "qwen2.5-32b", *, mode: str = "paged_merge",
+def build(arch: Union[str, ModelConfig] = "qwen2.5-32b", *,
+          mode: str = "paged_merge",
           batch: int = 8, max_seq: int = 256, near_window: Optional[int] = None,
           block_tokens: int = 8, pool_budget: float = 1.0, seed: int = 0,
           mesh_spec: str = "1x1", lanes: int = 0, mesh=None, params=None,
@@ -53,11 +70,11 @@ def build(arch: str = "qwen2.5-32b", *, mode: str = "paged_merge",
     lane N times (mutually exclusive with a multi-lane mesh_spec).
     Remaining ``engine_kw`` pass through to :class:`EngineConfig`.
     """
-    cfg = get_reduced(arch)
+    cfg = resolve_config(arch)
     # legacy spelling from pre-§14 call sites
     pool_budget = engine_kw.pop("pool_budget_frac", pool_budget)
     if params is None:
-        params = cached_params(arch, seed)
+        params = cached_params(cfg, seed)
     if mesh is not None:
         meshes: List = [mesh]
     else:

@@ -6,7 +6,6 @@ pipeline depths / dtypes / dma modes, the chunked-prefill twin, the
 interpret-resolution helper, and the engine-level token identity +
 audit-counter accounting.
 """
-import os
 
 import jax
 import jax.numpy as jnp
@@ -222,11 +221,11 @@ def test_chunk_skip_parity_and_identity(start, n_valid):
 # ---------------------------------------------------------------------------
 
 def test_resolve_interpret():
+    """Interpret mode exactly when the backend is the CPU; an explicit
+    argument wins."""
     assert resolve_interpret(True) is True
     assert resolve_interpret(False) is False
-    resolved = resolve_interpret(None)
-    if os.environ.get("REPRO_PALLAS_INTERPRET") is None:
-        assert resolved == (jax.default_backend() == "cpu")
+    assert resolve_interpret(None) == (jax.default_backend() == "cpu")
 
 
 # ---------------------------------------------------------------------------

@@ -144,6 +144,39 @@ def test_chunked_prefill_matches_ref(C, H, KV, hd, BT, NB, start, n_valid, dtype
     assert bool((np.asarray(out_p, np.float32)[n_valid:] == 0).all())
 
 
+@pytest.mark.parametrize("kv_dtype", ["f32", "int8"])
+def test_chunked_prefill_batched_matches_ref(kv_dtype):
+    """The served executor's batched launch (one chunk per slot, each at its
+    own start, fill and block table) equals the per-slot oracle."""
+    B, C, H, KV, hd, BT, NB = 3, 8, 4, 2, 32, 4, 6
+    P = NB * B + 2
+    q, pk, pv, tbl, _, _, _ = _mk_paged(jax.random.PRNGKey(5), B, H, KV, hd,
+                                        P, BT, NB, jnp.float32)
+    ks = jax.random.split(jax.random.PRNGKey(6), 5)
+    qc = jax.random.normal(ks[0], (B, C, H, hd), jnp.float32)
+    ck = jax.random.normal(ks[1], (B, C, KV, hd), jnp.float32)
+    cv = jax.random.normal(ks[2], (B, C, KV, hd), jnp.float32)
+    kw = {}
+    if kv_dtype == "int8":
+        pk, pv = (pk * 40).astype(jnp.int8), (pv * 40).astype(jnp.int8)
+        kw = dict(k_scale=jax.random.uniform(ks[3], (P, KV), minval=0.01,
+                                             maxval=0.03),
+                  v_scale=jax.random.uniform(ks[4], (P, KV), minval=0.01,
+                                             maxval=0.03))
+    wb = jnp.zeros(B, jnp.int32)
+    start = jnp.asarray([0, 5, 13], jnp.int32)
+    n_valid = jnp.asarray([8, 3, 6], jnp.int32)
+    W = NB * BT
+    out_p = chunked_prefill_attention_pallas(
+        qc, pk, pv, ck, cv, tbl, wb, start, n_valid, near_window=W, **kw)
+    out_r = jax.vmap(lambda q, k, v, t, w, s, n:
+                     ref.chunked_prefill_attention_ref(
+                         q, pk, pv, k, v, t, w, s, n, near_window=W, **kw))(
+        qc, ck, cv, tbl, wb, start, n_valid)
+    np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
+                               rtol=1e-5, atol=1e-5)
+
+
 def test_chunked_prefill_equals_token_at_a_time():
     """Feeding a chunk through the chunked kernel == feeding its tokens one
     at a time through the decode kernel with incremental pool writes."""
@@ -270,3 +303,52 @@ def test_paged_decode_property(B, KV, hd_pow, NB, data):
                                               near_window=W)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
                                rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# served decode contract: the current token rides outside the pool
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("depth", [0, 1])
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_decode_cur_token_matches_ref(kv_dtype, depth):
+    """The kernel with cur_k/cur_v (pool valid for pos < t, the current
+    token folded in at finalize) equals the oracle given the same inputs —
+    including a retired slot (zeros) and a slot at t=0 whose only key is
+    the current token."""
+    B, H, KV, hd, BT, NB = 4, 8, 2, 32, 8, 4
+    P = NB * B + 4
+    q, pk, pv, tbl, wb, seq, act = _mk_paged(
+        jax.random.PRNGKey(11), B, H, KV, hd, P, BT, NB, jnp.float32)
+    ks = jax.random.split(jax.random.PRNGKey(12), 4)
+    ck = jax.random.normal(ks[0], (B, KV, hd), jnp.float32)
+    cv = jax.random.normal(ks[1], (B, KV, hd), jnp.float32)
+    seq = seq.at[1].set(0)
+    act = act.at[2].set(0)
+    kw = {}
+    if kv_dtype == "int8":
+        pk = (pk * 40).astype(jnp.int8)
+        pv = (pv * 40).astype(jnp.int8)
+        kw = dict(k_scale=jax.random.uniform(ks[2], (P, KV), minval=0.01,
+                                             maxval=0.03),
+                  v_scale=jax.random.uniform(ks[3], (P, KV), minval=0.01,
+                                             maxval=0.03))
+    else:
+        q, pk, pv, ck, cv = (x.astype(jnp.bfloat16)
+                             for x in (q, pk, pv, ck, cv))
+    W = NB * BT
+    out_p, _ = paged_decode_attention_pallas(
+        q, pk, pv, tbl, wb, seq, act, near_window=W, cur_k=ck, cur_v=cv,
+        prefetch_depth=depth, **kw)
+    out_r, _ = ref.paged_decode_attention_ref(
+        q, pk, pv, tbl, wb, seq, act, near_window=W, cur_k=ck, cur_v=cv,
+        **kw)
+    tol = 2e-2 if kv_dtype == "bf16" else 1e-5
+    np.testing.assert_allclose(np.asarray(out_p, np.float32),
+                               np.asarray(out_r, np.float32),
+                               rtol=tol, atol=tol)
+    assert bool((np.asarray(out_p, np.float32)[2] == 0).all())
+    # t=0 with nothing in the pool: the output is the current token's value
+    cur_only = np.repeat(np.asarray(cv[1], np.float32), H // KV, axis=0)
+    np.testing.assert_allclose(np.asarray(out_p[1], np.float32), cur_only,
+                               rtol=tol, atol=tol)
