@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core import spans
 from repro.core.descriptor import (FrameDescriptor, active_block_extents,
                                    chunk_flat_size, control_plane_size,
                                    control_plane_views,
@@ -353,6 +354,9 @@ class KVRMEngine:
         # readback. Scrubbed overshoot emissions (§13) never fire it, and a
         # cancel's terminal event is the caller's to emit (no token lands).
         self.token_hook = None
+        # the clock of the request timeline stamps (t_release, t_admit,
+        # t_prompt_done); a gateway sets its own, as it sets token_hook
+        self.clock = time.perf_counter
         self.cancelled = 0
         # --- step-level admission audit (DESIGN.md §15) -----------------
         # continuous_admits counts admissions that landed while at least
@@ -573,6 +577,7 @@ class KVRMEngine:
                 "(greedy=False); legacy greedy mode is budget-EOS only. "
                 "For argmax decode WITH stop tokens use greedy=False, "
                 "temperature=0.")
+        req.t_release = self.clock()
         self.sched.submit(req)
 
     def cancel(self, rid: int) -> bool:
@@ -638,18 +643,26 @@ class KVRMEngine:
         surviving slots keep stepping. The round-based baseline instead
         holds the scheduler at a barrier (``hold=True``, which audits the
         stall) until the current round has fully drained."""
-        if not self.e.continuous_batching and self.sched.active_slots():
-            self.sched.admit(now, hold=True)
-            return
-        self._admit_into_free_slots(now)
+        with spans.Span(spans.SCHED_ADMIT) as sp:
+            if not self.e.continuous_batching and self.sched.active_slots():
+                self.sched.admit(now, hold=True)
+                n = 0
+            else:
+                n = self._admit_into_free_slots(now)
+            sp.stats(admitted=n)
 
-    def _admit_into_free_slots(self, now: float) -> None:
+    def _admit_into_free_slots(self, now: float) -> int:
+        """Admit into free slots; returns how many were admitted."""
         kv_ok = self._admission_ok if self._host_tier else None
         self._resume_pending = 0         # per-admit-call swap-in demand
         # an admission is "mid-round" when another slot is already decoding
         # — the case a round-based engine would have left this slot idle
         mid_round = bool(self.sched.active_slots())
-        for slot, req, sid in self.sched.admit(now, kv_ok=kv_ok):
+        admitted = self.sched.admit(now, kv_ok=kv_ok)
+        t_admit = self.clock() if admitted else 0.0
+        for slot, req, sid in admitted:
+            if req.t_admit is None:
+                req.t_admit = t_admit    # a resume keeps its first admission
             self._win_dirty[slot] = True
             self._win_base_cache[slot] = -1
             self._feed_ok[slot] = False
@@ -740,6 +753,7 @@ class KVRMEngine:
             self._cow_origin.clear()
             self.transport.account_cow(pairs)
             self._cow_copy(pairs)
+        return len(admitted)
 
     # ------------------------------------------------------------------
     # prefix cache: admission match / prompt indexing / COW copies (§9)
@@ -977,9 +991,9 @@ class KVRMEngine:
                                         "host_slots": list(host_slots),
                                         "sid": sid})
             return
-        t0 = time.perf_counter()
-        self._land_swap_out(gathers, host_slots, n)
-        self.swap_stall_s += time.perf_counter() - t0
+        with spans.Span(spans.SWAP_WAIT) as sw:
+            self._land_swap_out(gathers, host_slots, n)
+        self.swap_stall_s += sw.seconds
 
     def _land_swap_out(self, gathers, host_slots, n: int) -> None:
         """Synchronize one swap-out's gathers into the host backing pool."""
@@ -996,12 +1010,12 @@ class KVRMEngine:
         pend = self.transport.fence_drain_all()
         if not pend:
             return
-        t0 = time.perf_counter()
-        for p in pend:
-            self._land_swap_out(p["gathers"], p["host_slots"], p["n"])
-            if p["sid"] >= 0:
-                self.pager.swap_out_commit(p["sid"])
-        self.swap_stall_s += time.perf_counter() - t0
+        with spans.Span(spans.SWAP_WAIT) as sw:
+            for p in pend:
+                self._land_swap_out(p["gathers"], p["host_slots"], p["n"])
+                if p["sid"] >= 0:
+                    self.pager.swap_out_commit(p["sid"])
+        self.swap_stall_s += sw.seconds
 
     def _stage_buf(self, k: str) -> np.ndarray:
         """Preallocated, double-buffered host staging for one pool key's
@@ -1033,25 +1047,27 @@ class KVRMEngine:
         idx = np.zeros(self._swap_pad, np.int32)
         idx[:n] = dev_blocks
         jidx = jnp.asarray(idx)
-        t0 = time.perf_counter()
-        for k in self._swap_keys:
-            arr = self.pools[k]
-            if self.e.async_movement:
-                data = self._stage_buf(k)
-                data[:, :n] = np.moveaxis(self._host_kv[k][host_slots], 0, 1)
-                data[:, n:] = 0      # padding targets scratch block 0
-            else:
-                # A/B baseline: the PR-5 per-event allocation
-                data = np.zeros((arr.shape[0], self._swap_pad)
-                                + tuple(arr.shape[2:]),
-                                self._host_kv[k].dtype)
-                data[:, :n] = np.moveaxis(self._host_kv[k][host_slots], 0, 1)
-            # a snapshot of the staging buffer: the scatter may still be
-            # queued when this buffer is reused two transfers later
-            self.pools[k] = self._swap_scatter_fn(arr, jidx,
-                                                  jnp.asarray(data.copy()))
-        self._stage_sel ^= 1
-        self.swap_stall_s += time.perf_counter() - t0
+        with spans.Span(spans.SWAP_WAIT) as sw:
+            for k in self._swap_keys:
+                arr = self.pools[k]
+                if self.e.async_movement:
+                    data = self._stage_buf(k)
+                    data[:, :n] = np.moveaxis(
+                        self._host_kv[k][host_slots], 0, 1)
+                    data[:, n:] = 0      # padding targets scratch block 0
+                else:
+                    # A/B baseline: the PR-5 per-event allocation
+                    data = np.zeros((arr.shape[0], self._swap_pad)
+                                    + tuple(arr.shape[2:]),
+                                    self._host_kv[k].dtype)
+                    data[:, :n] = np.moveaxis(
+                        self._host_kv[k][host_slots], 0, 1)
+                # a snapshot of the staging buffer: the scatter may still be
+                # queued when this buffer is reused two transfers later
+                self.pools[k] = self._swap_scatter_fn(arr, jidx,
+                                                      jnp.asarray(data.copy()))
+            self._stage_sel ^= 1
+        self.swap_stall_s += sw.seconds
 
     def _first_window_local(self, s, t: int) -> int:
         """Local block index where the near window starts for a session at
@@ -1164,10 +1180,11 @@ class KVRMEngine:
         if not self._host_tier:
             return
         total = self.num_blocks - 1
-        if (total - self.pager.free_blocks()) / total \
-                > self.e.swap_high_watermark:
-            self._cold_swap(int(np.ceil(
-                (1.0 - self.e.swap_low_watermark) * total)))
+        with spans.Span(spans.PAGER_PRESSURE):
+            if (total - self.pager.free_blocks()) / total \
+                    > self.e.swap_high_watermark:
+                self._cold_swap(int(np.ceil(
+                    (1.0 - self.e.swap_low_watermark) * total)))
 
     def _swap_victim(self) -> Optional[int]:
         """Latest-admitted swap-eligible active slot (protects the oldest
@@ -1241,41 +1258,42 @@ class KVRMEngine:
         slots are pinned (their descriptor rows reference their blocks)."""
         if not self._host_tier:
             return
-        while True:
-            need = 0
-            for slot in self.sched.active_slots():
-                sid = int(self._slot_sid[slot])
-                if sid < 0:
-                    continue
-                if self._chunked and \
-                        self.sched.chunk_remaining(slot) >= self._chunk_min:
-                    n_tok = min(self.chunk, self.sched.chunk_remaining(slot))
-                else:
-                    n_tok = 2                  # this token + lookahead
-                need += self.pager.blocks_needed(sid, n_tok)
-            if self.pager.free_blocks() >= need:
-                return
-            self._cold_swap(need)
-            if self.pager.free_blocks() >= need:
-                return
-            # §9 pressure ladder: before preempting live work, reclaim
-            # prefix-cache blocks — unpinned unshared cold leaves free
-            # device blocks outright
-            if self.prefix_cache is not None:
-                self.prefix_cache.evict(need - self.pager.free_blocks())
+        with spans.Span(spans.PAGER_PRESSURE):
+            while True:
+                need = 0
+                for slot in self.sched.active_slots():
+                    sid = int(self._slot_sid[slot])
+                    if sid < 0:
+                        continue
+                    if self._chunked and \
+                            self.sched.chunk_remaining(slot) >= self._chunk_min:
+                        n_tok = min(self.chunk, self.sched.chunk_remaining(slot))
+                    else:
+                        n_tok = 2                  # this token + lookahead
+                    need += self.pager.blocks_needed(sid, n_tok)
                 if self.pager.free_blocks() >= need:
                     return
-            victim = self._swap_victim()
-            if victim is None:
-                # no swap-eligible victim: cached shares may be what holds
-                # every session's refcounts above 1 — flush the index
-                # (sessions keep their own refs; only reuse is lost) and
-                # retry the whole ladder once more
-                if self.prefix_cache is not None \
-                        and self.prefix_cache.flush_for_pressure():
-                    continue
-                return                         # backstop: _reserve raises
-            self._preempt_slot(victim)         # loop: recompute without it
+                self._cold_swap(need)
+                if self.pager.free_blocks() >= need:
+                    return
+                # §9 pressure ladder: before preempting live work, reclaim
+                # prefix-cache blocks — unpinned unshared cold leaves free
+                # device blocks outright
+                if self.prefix_cache is not None:
+                    self.prefix_cache.evict(need - self.pager.free_blocks())
+                    if self.pager.free_blocks() >= need:
+                        return
+                victim = self._swap_victim()
+                if victim is None:
+                    # no swap-eligible victim: cached shares may be what holds
+                    # every session's refcounts above 1 — flush the index
+                    # (sessions keep their own refs; only reuse is lost) and
+                    # retry the whole ladder once more
+                    if self.prefix_cache is not None \
+                            and self.prefix_cache.flush_for_pressure():
+                        continue
+                    return                         # backstop: _reserve raises
+                self._preempt_slot(victim)         # loop: recompute without it
 
     def _reserve(self, slot: int, sid: int, n_tokens: int):
         """pager.reserve with preemption-aware pressure relief: on device
@@ -1315,56 +1333,67 @@ class KVRMEngine:
         cd = self._cdescr
         self._chunk_wait = 0.0
         any_chunk = False
-        for slot in self.sched.active_slots():
-            if self.sched.chunk_remaining(slot) < self._chunk_min:
-                continue
-            if not any_chunk:
-                cd.n_valid[:] = 0
-                any_chunk = True
-            self._step_touched.add(slot)
-            toks = self.sched.consume_prompt_chunk(slot, C)
-            n = len(toks)
-            t0 = int(self._slot_len[slot])
-            if self.e.mode == "arena":
-                base = self._arena_base[slot]
-                idx = t0 + np.arange(n)
-                wblk = (base + idx // self.bt).astype(np.int32)
-                woff = (idx % self.bt).astype(np.int32)
-            else:
-                sid = int(self._slot_sid[slot])
-                self._reserve(slot, sid, n)
-                wblk, woff = self.pager.append_tokens(sid, n)
-            # context = the near window as seen by the chunk's FIRST query;
-            # later queries only need a suffix of it (masked in-kernel)
-            blocks, wb = self._window_blocks(slot)
-            cd.tokens[slot, :n] = toks
-            cd.tokens[slot, n:] = 0
-            cd.start_pos[slot] = t0
-            cd.n_valid[slot] = n
-            cd.block_table[slot] = blocks
-            cd.window_base[slot] = wb
-            cd.write_block[slot, :n] = wblk
-            cd.write_block[slot, n:] = 0
-            cd.write_offset[slot, :n] = woff
-            cd.write_offset[slot, n:] = 0
-            self._slot_len[slot] += n
-            self._win_dirty[slot] = True
-            self._chunk_steps += 1
+        with spans.Span(spans.PREFILL_ASSEMBLE) as sp:
+            rows = tokens = 0
+            for slot in self.sched.active_slots():
+                if self.sched.chunk_remaining(slot) < self._chunk_min:
+                    continue
+                if not any_chunk:
+                    cd.n_valid[:] = 0
+                    any_chunk = True
+                self._step_touched.add(slot)
+                self.sched.request_at(slot).prompt_chunk_steps += 1
+                toks = self.sched.consume_prompt_chunk(slot, C)
+                n = len(toks)
+                rows += 1
+                tokens += n
+                t0 = int(self._slot_len[slot])
+                if self.e.mode == "arena":
+                    base = self._arena_base[slot]
+                    idx = t0 + np.arange(n)
+                    wblk = (base + idx // self.bt).astype(np.int32)
+                    woff = (idx % self.bt).astype(np.int32)
+                else:
+                    sid = int(self._slot_sid[slot])
+                    self._reserve(slot, sid, n)
+                    wblk, woff = self.pager.append_tokens(sid, n)
+                # context = the near window as seen by the chunk's FIRST
+                # query; later queries only need a suffix of it (masked
+                # in-kernel)
+                blocks, wb = self._window_blocks(slot)
+                cd.tokens[slot, :n] = toks
+                cd.tokens[slot, n:] = 0
+                cd.start_pos[slot] = t0
+                cd.n_valid[slot] = n
+                cd.block_table[slot] = blocks
+                cd.window_base[slot] = wb
+                cd.write_block[slot, :n] = wblk
+                cd.write_block[slot, n:] = 0
+                cd.write_offset[slot, :n] = woff
+                cd.write_offset[slot, n:] = 0
+                self._slot_len[slot] += n
+                self._win_dirty[slot] = True
+                self._chunk_steps += 1
+            sp.stats(rows=rows, tokens=tokens)
         if any_chunk:
-            td = time.perf_counter()
-            self.pools = self._chunk_fn(self.params, self.pools,
-                                        jnp.asarray(self._cflat.copy()))
+            with spans.Span(spans.CHUNK_DISPATCH) as dsp:
+                self.pools = self._chunk_fn(self.params, self.pools,
+                                            jnp.asarray(self._cflat.copy()))
             # dispatch can block on the runtime's in-flight queue while the
             # PREVIOUS step still executes — that wait is device occupancy,
             # not host control work; the pipelined path subtracts it from
             # m.host so submit_share keeps measuring the control plane
-            self._chunk_wait = time.perf_counter() - td
+            self._chunk_wait = dsp.seconds
 
     # ------------------------------------------------------------------
     def step(self, now: float = float("inf")) -> StepMetrics:
-        if self.depth <= 0:
-            return self._step_sync(now)
-        return self._step_pipelined(now)
+        with spans.step_span(self.steps_run) as sp:
+            if self.depth <= 0:
+                m = self._step_sync(now, sp)
+            else:
+                m = self._step_pipelined(now, sp)
+            sp.stats(active=m.active, emitted=m.emitted)
+        return m
 
     # ------------------------------------------------------------------
     def _account_kernel_blocks(self, window_base, seq_lens, slot_active):
@@ -1389,10 +1418,10 @@ class KVRMEngine:
         return None
 
     # ------------------------------------------------------------------
-    def _step_sync(self, now: float) -> StepMetrics:
+    def _step_sync(self, now: float, sp: spans.Span) -> StepMetrics:
         """Seed-exact synchronous step: per-slot descriptor assembly, one
-        blocking readback per step (pipeline_depth=0 A/B baseline)."""
-        t0 = time.perf_counter()
+        blocking readback per step (pipeline_depth=0 A/B baseline). ``sp``
+        is the step's span; its clock times ``m.host`` and ``m.wall``."""
         m = StepMetrics()
         self.sched.step_idx = self.steps_run
 
@@ -1406,12 +1435,63 @@ class KVRMEngine:
         active = self.sched.active_slots()
         m.active = len(active)
 
+        with spans.Span(spans.DECODE_ASSEMBLE):
+            descr, tokens, parts, prompt_done = self._assemble_sync(active, m)
+
+        # ---- Frame: single atomic commit
+        with spans.Span(spans.FRAME_COMMIT) as fc:
+            if self.pager is not None:
+                frame = self.pager.frame()
+                descr = descr._replace(epoch=np.int32(frame["epoch"]))
+                self.frames_committed += 1
+            else:
+                descr = descr._replace(epoch=np.int32(self.steps_run + 1))
+        m.frame_commit = fc.seconds
+
+        with spans.Span(spans.DECODE_DISPATCH):
+            jdescr = FrameDescriptor(*[jnp.asarray(a) for a in descr])
+            # chunk-dispatch queue wait is device occupancy, not control
+            # work (zero when prefill_chunk=0, keeping the seed path
+            # bit-exact)
+            m.host = max(0.0, sp.elapsed() - self._chunk_wait)
+
+            # ---- device: one engine call, fixed shapes
+            self.transport.note_dispatch_overlap()
+            nxt, self.pools, fu, lg = self._dispatch_step(
+                self.params, jnp.asarray(tokens), self._zero_feed,
+                jnp.asarray(self._rid_buf), self._prev_nxt, self.pools, jdescr)
+        self._prev_nxt = nxt
+        if prompt_done:
+            t = self.clock()
+            for req in prompt_done:
+                req.t_prompt_done = t
+        with spans.Span(spans.READBACK) as rb:
+            with spans.Span(spans.DEVICE_WAIT):
+                nxt = np.asarray(jax.block_until_ready(nxt))
+            if self.e.debug_logits:
+                self.debug_logits.append(np.asarray(lg, np.float32))
+            self._post_sync(parts, nxt, lg, fu, descr, m)
+            rb.stats(emits=m.emitted)
+
+        self.steps_run += 1
+        m.wall = sp.elapsed()
+        self.cum_wall += m.wall
+        self.peak_reserved_kv = max(self.peak_reserved_kv, self.reserved_kv_bytes())
+        self.peak_active_kv = max(self.peak_active_kv, self.active_kv_bytes())
+        self.metrics.append(m)
+        return m
+
+    def _assemble_sync(self, active, m: StepMetrics):
+        """Per-slot descriptor assembly of the synchronous step. Returns the
+        descriptor, the host tokens, the participating slots and the
+        requests whose last prompt token rides this step."""
         B = self.e.batch
         descr = empty_descriptor(B, self.NB, self.cap, self.MT,
                                  chunk_blocks=self.chunk_blocks)
         tokens = np.zeros(B, np.int32)
 
         parts = []                       # slots participating in this step
+        prompt_done = []
         for slot in active:
             req = self.sched.request_at(slot)
             if req is None:
@@ -1423,6 +1503,10 @@ class KVRMEngine:
             self._step_touched.add(slot)
             was_prefilling = self.sched.is_prefilling(slot)
             tokens[slot] = self.sched.next_token(slot, int(self._last_token[slot]))
+            if was_prefilling:
+                req.prompt_tail_steps += 1
+                if req.prompt_pos >= len(req.prompt):
+                    prompt_done.append(req)
             if self.prefix_cache is not None and was_prefilling \
                     and req.prompt_pos >= len(req.prompt):
                 self._prefix_index(slot, req)    # prompt committed: index
@@ -1465,33 +1549,11 @@ class KVRMEngine:
             # §15: each participating mid-round-admitted slot is one
             # slot-step a round barrier would have left idle
             self.slot_idle_steps_saved += int(self._mid_round[parts].sum())
+        return descr, tokens, parts, prompt_done
 
-        # ---- Frame: single atomic commit
-        tf0 = time.perf_counter()
-        if self.pager is not None:
-            frame = self.pager.frame()
-            descr = descr._replace(epoch=np.int32(frame["epoch"]))
-            self.frames_committed += 1
-        else:
-            descr = descr._replace(epoch=np.int32(self.steps_run + 1))
-        m.frame_commit = time.perf_counter() - tf0
-
-        jdescr = FrameDescriptor(*[jnp.asarray(a) for a in descr])
-        # chunk-dispatch queue wait is device occupancy, not control work
-        # (zero when prefill_chunk=0, keeping the seed path bit-exact)
-        m.host = max(0.0, time.perf_counter() - t0 - self._chunk_wait)
-
-        # ---- device: one engine call, fixed shapes
-        self.transport.note_dispatch_overlap()
-        nxt, self.pools, fu, lg = self._dispatch_step(
-            self.params, jnp.asarray(tokens), self._zero_feed,
-            jnp.asarray(self._rid_buf), self._prev_nxt, self.pools, jdescr)
-        self._prev_nxt = nxt
-        nxt = np.asarray(jax.block_until_ready(nxt))
-        if self.e.debug_logits:
-            self.debug_logits.append(np.asarray(lg, np.float32))
-
-        # ---- post: bookkeeping, EOS retirement (burst-safe)
+    def _post_sync(self, parts, nxt, lg, fu, descr, m: StepMetrics) -> None:
+        """The synchronous step's post-device bookkeeping: token values,
+        EOS retirement (burst-safe), far-view utility."""
         for slot in parts:
             self._slot_len[slot] += 1
             if self.sched.is_prefilling(slot):
@@ -1517,22 +1579,14 @@ class KVRMEngine:
         if self.fv is not None:
             self.fv.observe_utility(np.asarray(fu), np.asarray(descr.far_table))
 
-        self.steps_run += 1
-        m.wall = time.perf_counter() - t0
-        self.cum_wall += m.wall
-        self.peak_reserved_kv = max(self.peak_reserved_kv, self.reserved_kv_bytes())
-        self.peak_active_kv = max(self.peak_active_kv, self.active_kv_bytes())
-        self.metrics.append(m)
-        return m
-
     # ------------------------------------------------------------------
-    def _step_pipelined(self, now: float) -> StepMetrics:
+    def _step_pipelined(self, now: float, sp: spans.Span) -> StepMetrics:
         """Overlapped step: assemble + dispatch step t, then read back step
         t-depth while the device runs. Descriptor assembly is vectorized over
         slots with an incrementally maintained window-block/train cache —
         per-slot Python work happens only on admit/trim/alias/reserve or a
-        window slide, not every step."""
-        t0 = time.perf_counter()
+        window slide, not every step. ``sp`` is the step's span; its clock
+        times ``m.host`` and ``m.wall``."""
         m = StepMetrics()
         self.sched.step_idx = self.steps_run
 
@@ -1545,6 +1599,77 @@ class KVRMEngine:
         active = self.sched.active_slots()
         m.active = len(active)
 
+        with spans.Span(spans.DECODE_ASSEMBLE):
+            parts, emits, eos_meta, prompt_done = \
+                self._assemble_pipelined(active, m)
+
+        # ---- Frame: single atomic commit
+        d = self._pdescr
+        with spans.Span(spans.FRAME_COMMIT) as fc:
+            if self.pager is not None:
+                frame = self.pager.frame()
+                d.epoch[...] = frame["epoch"]
+                self.frames_committed += 1
+            else:
+                d.epoch[...] = self.steps_run + 1
+        m.frame_commit = fc.seconds
+
+        with spans.Span(spans.DECODE_DISPATCH):
+            # ONE host->device transfer, of a snapshot: jax may read (or, on
+            # the CPU, alias) a host array after dispatch returns, and _flat
+            # is rewritten in place for the next step while this one is in
+            # flight
+            jflat = jnp.asarray(self._flat.copy())
+            # chunk-dispatch queue wait is device occupancy, not control work
+            m.host = max(0.0, sp.elapsed() - self._chunk_wait)
+
+            # ---- device: dispatch step t (async), keep host moving
+            self.transport.note_dispatch_overlap()
+            nxt, self.pools, fu, lg = self._dispatch_step(
+                self.params, jflat, self._prev_nxt, self.pools)
+        self._prev_nxt = nxt
+        if prompt_done:
+            t = self.clock()
+            for req in prompt_done:
+                req.t_prompt_done = t
+
+        # ---- structural bookkeeping at DISPATCH time. Legacy greedy: EOS
+        # is the fixed gen_len budget, so retirement is host-predictable
+        # here and pager/transport timelines stay bit-identical to the
+        # synchronous path. Sampled (§13): EOS is data-dependent, so NOTHING
+        # retires at dispatch — stop AND budget retirement both happen at
+        # readback, where overshot dispatches are scrubbed via ``eos_meta``.
+        # Token VALUES land at readback either way, ``depth`` steps later.
+        m.emitted = len(emits)
+        for slot in parts:
+            self._slot_len[slot] += 1
+        for slot, req in emits:
+            self._feed_ok[slot] = True
+            if self.sched.note_emit(slot) and not self._sampled:
+                self._retire_slot(slot)
+
+        self._inflight.append({
+            "nxt": nxt, "lg": lg, "fu": fu, "emits": emits,
+            "m": m, "eos": eos_meta,
+            "far_table": d.far_table.copy() if self.fv is not None else None,
+        })
+        while len(self._inflight) > self.depth:
+            self._readback(self._inflight.popleft())
+
+        self.steps_run += 1
+        m.wall = sp.elapsed()
+        self.cum_wall += m.wall
+        self.peak_reserved_kv = max(self.peak_reserved_kv, self.reserved_kv_bytes())
+        self.peak_active_kv = max(self.peak_active_kv, self.active_kv_bytes())
+        self.metrics.append(m)
+        return m
+
+    def _assemble_pipelined(self, active, m: StepMetrics):
+        """Vectorized descriptor assembly of the pipelined step into the
+        persistent flat buffer. Returns the participating slots, the
+        (slot, request) pairs emitting this step, the sampled-mode
+        overshoot snapshot and the requests whose last prompt token rides
+        this step."""
         d = self._pdescr
         tokens = self._tokens_buf
         feed = self._feed_buf
@@ -1559,6 +1684,7 @@ class KVRMEngine:
 
         parts: List[int] = []
         emits: List[tuple] = []          # (slot, req) emitting this step
+        prompt_done: List[Request] = []
         resv: Dict[int, list] = {}       # slot -> blocks THIS step reserved
         kskip = None                     # per-slot kernel blocks predicated
         far_flags = None
@@ -1572,6 +1698,8 @@ class KVRMEngine:
             self._step_touched.add(slot)
             was_prefilling = req.prompt_pos < len(req.prompt)
             tokens[slot] = self.sched.next_token(slot, int(self._last_token[slot]))
+            if was_prefilling:
+                req.prompt_tail_steps += 1
             if not was_prefilling and req.emitted > 0 and self._feed_ok[slot]:
                 # decode continuation: token comes from the device-side argmax
                 # of the previous dispatched step (one-step lag, no readback).
@@ -1582,8 +1710,10 @@ class KVRMEngine:
             parts.append(slot)
             if req.prompt_pos >= len(req.prompt):
                 emits.append((slot, req))
-                if self.prefix_cache is not None and was_prefilling:
-                    self._prefix_index(slot, req)    # prompt committed
+                if was_prefilling:
+                    prompt_done.append(req)
+                    if self.prefix_cache is not None:
+                        self._prefix_index(slot, req)    # prompt committed
 
             t = int(self._slot_len[slot])
             if self.e.mode == "arena":
@@ -1668,60 +1798,7 @@ class KVRMEngine:
                     "far": int(far_flags[i]) if far_flags is not None else 0,
                     "kskip": int(kskip[i]) if kskip is not None else 0,
                 }
-
-        # ---- Frame: single atomic commit
-        tf0 = time.perf_counter()
-        if self.pager is not None:
-            frame = self.pager.frame()
-            d.epoch[...] = frame["epoch"]
-            self.frames_committed += 1
-        else:
-            d.epoch[...] = self.steps_run + 1
-        m.frame_commit = time.perf_counter() - tf0
-
-        # ONE host->device transfer, of a snapshot: jax may read (or, on
-        # the CPU, alias) a host array after dispatch returns, and _flat is
-        # rewritten in place for the next step while this one is in flight
-        jflat = jnp.asarray(self._flat.copy())
-        # chunk-dispatch queue wait is device occupancy, not control work
-        m.host = max(0.0, time.perf_counter() - t0 - self._chunk_wait)
-
-        # ---- device: dispatch step t (async), keep host moving
-        self.transport.note_dispatch_overlap()
-        nxt, self.pools, fu, lg = self._dispatch_step(
-            self.params, jflat, self._prev_nxt, self.pools)
-        self._prev_nxt = nxt
-
-        # ---- structural bookkeeping at DISPATCH time. Legacy greedy: EOS
-        # is the fixed gen_len budget, so retirement is host-predictable
-        # here and pager/transport timelines stay bit-identical to the
-        # synchronous path. Sampled (§13): EOS is data-dependent, so NOTHING
-        # retires at dispatch — stop AND budget retirement both happen at
-        # readback, where overshot dispatches are scrubbed via ``eos_meta``.
-        # Token VALUES land at readback either way, ``depth`` steps later.
-        m.emitted = len(emits)
-        for slot in parts:
-            self._slot_len[slot] += 1
-        for slot, req in emits:
-            self._feed_ok[slot] = True
-            if self.sched.note_emit(slot) and not self._sampled:
-                self._retire_slot(slot)
-
-        self._inflight.append({
-            "nxt": nxt, "lg": lg, "fu": fu, "emits": emits,
-            "m": m, "eos": eos_meta,
-            "far_table": d.far_table.copy() if self.fv is not None else None,
-        })
-        while len(self._inflight) > self.depth:
-            self._readback(self._inflight.popleft())
-
-        self.steps_run += 1
-        m.wall = time.perf_counter() - t0
-        self.cum_wall += m.wall
-        self.peak_reserved_kv = max(self.peak_reserved_kv, self.reserved_kv_bytes())
-        self.peak_active_kv = max(self.peak_active_kv, self.active_kv_bytes())
-        self.metrics.append(m)
-        return m
+        return parts, emits, eos_meta, prompt_done
 
     # ------------------------------------------------------------------
     def _dispatch_step(self, *args):
@@ -1743,52 +1820,56 @@ class KVRMEngine:
         return self._step_fn.lower(*self._step_sig).compile().as_text()
 
     # ------------------------------------------------------------------
-    def _readback(self, rec: dict) -> None:
+    def _readback(self, rec: dict) -> float:
         """Value bookkeeping for one in-flight step: sampled tokens, logit
-        traces, far-view utility feedback (one step of lag under pipelining)."""
-        nxt = np.asarray(jax.block_until_ready(rec["nxt"]))
-        lg = None
-        if self.e.debug_logits:
-            lg = np.asarray(rec["lg"], np.float32)
-            self.debug_logits.append(lg)
-        for slot, req in rec["emits"]:
-            tok = int(nxt[slot])
-            req.generated.append(tok)
-            # wall-clock latencies stamp when the VALUE is known (readback),
-            # not at dispatch — comparable with the synchronous path and
-            # never flattered by the one-step pipeline lag
-            if len(req.generated) == 1:
-                req.ttft_wall = self.cum_wall
-            fin = False
-            if not self._sampled and req.emitted >= req.gen_len \
-                    and len(req.generated) >= req.gen_len:
-                req.finish_wall = self.cum_wall
-                fin = True
-            if lg is not None:
-                if not hasattr(req, "logit_trace"):
-                    req.logit_trace = []
-                req.logit_trace.append(lg[slot])
-            if self.sched.slots[slot].rid == req.rid:
-                self._last_token[slot] = tok
-            if self._sampled:
-                # sampled decode (§13): ALL retirement is readback-side.
-                # The host learns of a stop ``depth`` steps late — scrub
-                # the overshoot dispatches still in flight, then retire.
-                stop = bool(req.stop_tokens) and tok in req.stop_tokens
-                if stop or len(req.generated) >= req.gen_len:
-                    req.eos_hit = stop
-                    req.finish_reason = "stop" if stop else "budget"
-                    if stop:
-                        self.eos_detected += 1
-                    assert self.sched.slots[slot].rid == req.rid, \
-                        "sampled mode never retires at dispatch"
-                    self._scrub_overshoot(slot, req)
-                    self._retire_slot(slot)
+        traces, far-view utility feedback (one step of lag under pipelining).
+        Returns the seconds it took."""
+        with spans.Span(spans.READBACK, emits=len(rec["emits"])) as sp:
+            with spans.Span(spans.DEVICE_WAIT):
+                nxt = np.asarray(jax.block_until_ready(rec["nxt"]))
+            lg = None
+            if self.e.debug_logits:
+                lg = np.asarray(rec["lg"], np.float32)
+                self.debug_logits.append(lg)
+            for slot, req in rec["emits"]:
+                tok = int(nxt[slot])
+                req.generated.append(tok)
+                # wall-clock latencies stamp when the VALUE is known (readback),
+                # not at dispatch — comparable with the synchronous path and
+                # never flattered by the one-step pipeline lag
+                if len(req.generated) == 1:
+                    req.ttft_wall = self.cum_wall
+                fin = False
+                if not self._sampled and req.emitted >= req.gen_len \
+                        and len(req.generated) >= req.gen_len:
+                    req.finish_wall = self.cum_wall
                     fin = True
-            if self.token_hook is not None:
-                self.token_hook(req, tok, fin)
-        if self.fv is not None:
-            self.fv.observe_utility(np.asarray(rec["fu"]), rec["far_table"])
+                if lg is not None:
+                    if not hasattr(req, "logit_trace"):
+                        req.logit_trace = []
+                    req.logit_trace.append(lg[slot])
+                if self.sched.slots[slot].rid == req.rid:
+                    self._last_token[slot] = tok
+                if self._sampled:
+                    # sampled decode (§13): ALL retirement is readback-side.
+                    # The host learns of a stop ``depth`` steps late — scrub
+                    # the overshoot dispatches still in flight, then retire.
+                    stop = bool(req.stop_tokens) and tok in req.stop_tokens
+                    if stop or len(req.generated) >= req.gen_len:
+                        req.eos_hit = stop
+                        req.finish_reason = "stop" if stop else "budget"
+                        if stop:
+                            self.eos_detected += 1
+                        assert self.sched.slots[slot].rid == req.rid, \
+                            "sampled mode never retires at dispatch"
+                        self._scrub_overshoot(slot, req)
+                        self._retire_slot(slot)
+                        fin = True
+                if self.token_hook is not None:
+                    self.token_hook(req, tok, fin)
+            if self.fv is not None:
+                self.fv.observe_utility(np.asarray(rec["fu"]), rec["far_table"])
+        return sp.seconds
 
     def _scrub_overshoot(self, slot: int, req) -> None:
         """Reverse every in-flight dispatch issued for ``req`` AFTER its
@@ -1834,9 +1915,7 @@ class KVRMEngine:
         Drain time counts toward the wall so throughput/latency sums include
         the tail steps' device execution."""
         while self._inflight:
-            t0 = time.perf_counter()
-            self._readback(self._inflight.popleft())
-            dt = time.perf_counter() - t0
+            dt = self._readback(self._inflight.popleft())
             self.cum_wall += dt
             if self.metrics:
                 self.metrics[-1].wall += dt

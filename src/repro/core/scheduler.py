@@ -52,6 +52,18 @@ class Request:
     # recompute at retire time could no longer reproduce (the cache may
     # have changed since).
     committed_blocks: int = 0
+    # --- timeline (docs/OPERATIONS.md), on the engine's ``clock``: the
+    # gateway's when served through one. None until stamped.
+    t_release: Optional[float] = None     # handed to the engine (submit)
+    t_admit: Optional[float] = None       # first slot admission; a resume
+    #                                       after preemption keeps it
+    t_prompt_done: Optional[float] = None  # dispatch of the step carrying
+    #                                        the prompt's last token
+    prompt_chunk_steps: int = 0      # steps that ingested prompt tokens
+    #                                  through the chunk executor
+    prompt_tail_steps: int = 0       # decode steps that ingested one prompt
+    #                                  token (below the chunk minimum, and
+    #                                  always the prompt's last token)
 
 
 @dataclass

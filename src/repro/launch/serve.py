@@ -71,13 +71,16 @@ if __name__ == "__main__":
             _xf.apply_profile(_prof)
 
 import argparse
+import contextlib
 import json
+import threading
 import time
 
 import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import SamplingConfig
+from repro.core import spans
 from repro.core.engine import KVRMEngine
 from repro.data import traces
 from repro.launch import xla_flags
@@ -218,6 +221,53 @@ def run_gateway(engines: list, reqs, *, slo_class: str = "standard",
     }
 
 
+class ProfileWindow:
+    """A profiler capture of the serving run, written under ``trace_dir``
+    (open it in TensorBoard or Perfetto): from entry until ``seconds`` have
+    passed (0: until exit). The engine and gateway spans (``kvrm.*``) lie
+    in it on the device trace's clock; ``summary()`` totals the ones the
+    process kept in memory while it ran."""
+
+    def __init__(self, trace_dir: str, seconds: float = 0.0):
+        self.trace_dir, self.seconds = trace_dir, seconds
+        self._lock = threading.Lock()
+        self._timer = None
+        self._on = False
+
+    def __enter__(self):
+        import jax
+        spans.LOG.spans.clear()
+        jax.profiler.start_trace(self.trace_dir)
+        self._on = True
+        if self.seconds > 0:
+            self._timer = threading.Timer(self.seconds, self.stop)
+            self._timer.daemon = True
+            self._timer.start()
+        return self
+
+    def stop(self) -> None:
+        import jax
+        with self._lock:
+            if self._on:
+                jax.profiler.stop_trace()
+                self._on = False
+
+    def __exit__(self, *exc):
+        if self._timer is not None:
+            self._timer.cancel()
+        self.stop()
+        return False
+
+    def summary(self) -> dict:
+        """Count and mean milliseconds of each span captured (the span log
+        keeps spans only while a trace is captured)."""
+        per = {}
+        for name, t0, t1, _ in list(spans.LOG.spans):
+            per.setdefault(name, []).append(1e3 * (t1 - t0))
+        return {n: {"count": len(v), "mean_ms": sum(v) / len(v)}
+                for n, v in sorted(per.items())}
+
+
 def build_arg_parser() -> argparse.ArgumentParser:
     """The serve CLI surface. Kept in a named builder so the operator-doc
     regression test (tests/test_docs.py) can diff every flag against
@@ -339,6 +389,13 @@ def build_arg_parser() -> argparse.ArgumentParser:
                          "(serving/api.py: TTFT/TPOT targets + shed "
                          "depth); 'mixed' stripes all three classes "
                          "round-robin over the trace")
+    ap.add_argument("--trace-dir", default=None,
+                    help="capture a profiler trace of the run here: the "
+                         "engine and gateway spans (kvrm.*) on the device "
+                         "trace's clock; off by default")
+    ap.add_argument("--trace-seconds", type=float, default=0.0,
+                    help="with --trace-dir: stop the capture after this "
+                         "many seconds of serving (0 = the whole run)")
     ap.add_argument("--json", action="store_true")
     return ap
 
@@ -407,23 +464,29 @@ def main(argv=None):
     print("workload:", traces.trace_summary(reqs))
 
     scale = 0.02                # trace window -> wall seconds compression
-    if args.gateway:
-        out = run_gateway(engines, reqs, slo_class=args.slo_class,
-                          arrival_scale=scale)
-        out.pop("results")      # per-rid token streams: bench-only payload
-    else:
-        now_fn = None
-        if args.workload == "replay" or args.arrival != "trace":
-            # virtual-time replay: arrivals gate admission. The trace
-            # window is compressed into wall seconds up front (arrivals and
-            # the engine's latency stamps then share one clock; admission
-            # timing is equivalent to dividing now by the scale).
-            for r in reqs:
-                r.arrival *= scale
-            t0 = time.perf_counter()
-            now_fn = lambda: time.perf_counter() - t0
-        out = run_lanes(engines, reqs, now_fn=now_fn)
-        out["throughput_tok_s"] = out["aggregate_tok_s"]
+    window = (ProfileWindow(args.trace_dir, args.trace_seconds)
+              if args.trace_dir else contextlib.nullcontext())
+    with window:
+        if args.gateway:
+            out = run_gateway(engines, reqs, slo_class=args.slo_class,
+                              arrival_scale=scale)
+            out.pop("results")  # per-rid token streams: bench-only payload
+        else:
+            now_fn = None
+            if args.workload == "replay" or args.arrival != "trace":
+                # virtual-time replay: arrivals gate admission. The trace
+                # window is compressed into wall seconds up front (arrivals
+                # and the engine's latency stamps then share one clock;
+                # admission timing is equivalent to dividing now by the
+                # scale).
+                for r in reqs:
+                    r.arrival *= scale
+                t0 = time.perf_counter()
+                now_fn = lambda: time.perf_counter() - t0
+            out = run_lanes(engines, reqs, now_fn=now_fn)
+            out["throughput_tok_s"] = out["aggregate_tok_s"]
+    if args.trace_dir:
+        out["spans"] = window.summary()
     out["xla_profile"] = xla_flags.active_profile()
 
     if args.json:

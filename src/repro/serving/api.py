@@ -120,6 +120,14 @@ class RequestResult:
     ttft_s: float
     tpot_s: float
     finish_t: float
+    # where the first-token time went (``Gateway.timeline``); None where
+    # the request never reached that stamp
+    queue_s: Optional[float] = None        # arrival -> released to engine
+    admit_wait_s: Optional[float] = None   # released -> first slot admission
+    prefill_s: Optional[float] = None      # admitted -> last prompt token
+    #                                        dispatched
+    prompt_tail_steps: int = 0             # decode steps that ingested one
+    #                                        prompt token
 
     @property
     def slo_attained(self) -> bool:

@@ -14,7 +14,9 @@ steps every busy lane — the open-system analogue of the closed-loop
 The pump is cooperative: one engine step per lane per cycle with an
 ``await asyncio.sleep(0)`` between cycles, so streams and submitters
 interleave with decode. All timestamps are on one gateway clock
-(``perf_counter`` - t0, overridable for tests).
+(``perf_counter`` - t0, overridable for tests), which the engines' request
+timeline stamps share: ``timeline(rid)`` splits a first-token time into
+queue, admission wait, prefill and first-token lag.
 """
 from __future__ import annotations
 
@@ -23,6 +25,7 @@ import time
 from collections import deque
 from typing import AsyncIterator, Deque, Dict, List, Optional, Tuple
 
+from repro.core import spans
 from repro.serving.admission import AdmissionController, SLOOrderPolicy
 from repro.serving.api import (GenerationRequest, RequestResult, TokenEvent)
 from repro.serving.router import AffinityRouter, RoundRobinRouter  # noqa: F401
@@ -54,6 +57,7 @@ class Gateway:
         self._closed = False
         for lane, eng in enumerate(self.engines):
             eng.token_hook = self._hook_for(lane)
+            eng.clock = self._now
             if slo_order and eng.sched.policy is None:
                 eng.sched.policy = SLOOrderPolicy()
 
@@ -124,11 +128,34 @@ class Gateway:
     def result(self, rid: int) -> Optional[RequestResult]:
         return self._results.get(rid)
 
+    def timeline(self, rid: int) -> Optional[dict]:
+        """The request's stamps so far, on the gateway clock: ``arrival``,
+        ``t_release`` (handed to its engine), ``t_admit`` (first slot
+        admission), ``t_prompt_done`` (dispatch of the step carrying its
+        last prompt token), ``t_first`` (first token streamed), each None
+        until reached; and its ``prompt_chunk_steps``,
+        ``prompt_tail_steps`` and ``preemptions``. Consecutive stamps
+        split ``ttft_s`` exactly. None for an unknown rid."""
+        info = self._greqs.get(rid)
+        if info is None:
+            return None
+        req, meta = info[1], self._meta[rid]
+        return {"arrival": meta["arrival"], "t_release": req.t_release,
+                "t_admit": req.t_admit, "t_prompt_done": req.t_prompt_done,
+                "t_first": meta["first_t"],
+                "prompt_chunk_steps": req.prompt_chunk_steps,
+                "prompt_tail_steps": req.prompt_tail_steps,
+                "preemptions": req.preempt_count}
+
     def close(self) -> None:
         self._closed = True
         self._wake.set()
         for eng in self.engines:
             eng.token_hook = None
+        # the timelines of requests still running, as far as they got
+        for rid in self._greqs:
+            if rid not in self._results:
+                spans.LOG.add_timeline(rid, self.timeline(rid))
 
     # ------------------------------------------------------------------
     # admission introspection (AdmissionController reads these)
@@ -191,13 +218,21 @@ class Gateway:
         first, last, n = meta["first_t"], meta["last_t"], meta["n"]
         ttft = (first - meta["arrival"]) if first is not None else float("inf")
         tpot = ((last - first) / (n - 1)) if n and n > 1 else 0.0
+        tl = self.timeline(rid)
+        spans.LOG.add_timeline(rid, tl)
+        stage = lambda a, b: (None if tl[a] is None or tl[b] is None
+                              else tl[b] - tl[a])
         self._results[rid] = RequestResult(
             rid=rid, tokens=tuple(req.generated),
             finish_reason=req.finish_reason or "cancelled",
             slo=greq.slo, tenant=greq.tenant, arrival=meta["arrival"],
             ttft_s=max(0.0, ttft) if ttft != float("inf") else ttft,
             tpot_s=max(0.0, tpot),
-            finish_t=last if last is not None else self._now())
+            finish_t=last if last is not None else self._now(),
+            queue_s=stage("arrival", "t_release"),
+            admit_wait_s=stage("t_release", "t_admit"),
+            prefill_s=stage("t_admit", "t_prompt_done"),
+            prompt_tail_steps=req.prompt_tail_steps)
         self._out_class[greq.slo.name] -= 1
         if synthetic:
             q = self._events.get(rid)
@@ -238,18 +273,23 @@ class Gateway:
         qs = self._queues[lane]
         tenants = sorted(qs)
         cap = float("inf") if eng.e.continuous_batching else eng.e.batch
-        while tenants and len(eng.sched.waiting) < cap:
-            released = False
-            for k in range(len(tenants)):
-                t = tenants[(self._rr[lane] + k) % len(tenants)]
-                q = qs[t]
-                if q and q[0].arrival <= now:
-                    eng.submit(q.popleft())
-                    self._rr[lane] = (self._rr[lane] + k + 1) % len(tenants)
-                    released = True
+        n = 0
+        with spans.Span(spans.GATEWAY_RELEASE) as sp:
+            while tenants and len(eng.sched.waiting) < cap:
+                released = False
+                for k in range(len(tenants)):
+                    t = tenants[(self._rr[lane] + k) % len(tenants)]
+                    q = qs[t]
+                    if q and q[0].arrival <= now:
+                        eng.submit(q.popleft())
+                        self._rr[lane] = (self._rr[lane] + k + 1) \
+                            % len(tenants)
+                        released = True
+                        n += 1
+                        break
+                if not released:
                     break
-            if not released:
-                break
+            sp.stats(released=n)
 
     def _pending(self) -> int:
         return sum(len(q) for qs in self._queues for q in qs.values())

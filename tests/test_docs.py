@@ -41,6 +41,28 @@ def test_every_audit_field_documented():
         f"document each new counter with the invariant it witnesses")
 
 
+def test_every_request_result_field_documented():
+    from dataclasses import fields
+
+    from repro.serving.api import RequestResult
+    documented = _documented_keys(OPERATIONS.read_text())
+    missing = sorted({f.name for f in fields(RequestResult)} - documented)
+    assert not missing, (
+        f"RequestResult fields missing from docs/OPERATIONS.md: {missing}")
+
+
+def test_every_span_and_timeline_stamp_documented():
+    """The span vocabulary (names the benchmark reads and operators see
+    in a trace) and the timeline stamps."""
+    from repro.core import spans
+    text = OPERATIONS.read_text()
+    names = spans.SPANS + spans.TIMELINE_STAMPS + (
+        "prompt_chunk_steps", "prompt_tail_steps")
+    missing = [n for n in names if f"`{n}`" not in text]
+    assert not missing, (
+        f"spans/stamps missing from docs/OPERATIONS.md: {missing}")
+
+
 def test_audit_report_matches_live_audit():
     """The typed schema and a live ``engine.audit()`` dict agree exactly:
     same keys (``as_dict`` is the back-compat surface), no drift."""
