@@ -205,11 +205,16 @@ def unflatten_descriptor(flat: jnp.ndarray, batch: int, n_blocks: int,
 class PrefillChunkDescriptor(NamedTuple):
     """Fixed-shape view of one batched prompt-ingestion step (§3).
 
-    B = engine batch width, C = chunk width, NB = near-window blocks — all
-    fixed, same table geometry as the decode descriptor. Every slot row is
-    processed every call (ONE dispatch per engine step, like the decode
-    step); slots with nothing to ingest carry ``n_valid = 0`` and are fully
-    masked. A P-token prompt is ingested in ceil((P-1)/C) chunks — the
+    B = row width, C = chunk width, NB = near-window blocks — same table
+    geometry as the decode descriptor. A row is not a slot: the engine packs
+    each slot with chunk work this step into the next free row (ONE
+    dispatch per engine step, like the decode step) and dispatches the
+    smallest compiled row width that holds them (``chunk_row_widths``);
+    rows past the live ones carry ``n_valid = 0`` and are fully masked.
+    Each row carries its own positions, window table and write targets, so
+    nothing in the executor needs the slot index. The host keeps a B-row
+    view with the live rows first (``pack_chunk_rows`` takes the first
+    ``w`` of them). A P-token prompt is ingested in ceil((P-1)/C) chunks — the
     final prompt token always goes through the decode step so sampled-token
     semantics match the token-at-a-time path exactly. Chunks need not be
     block-aligned (aliased prefixes start mid-block): each chunk token
@@ -217,7 +222,7 @@ class PrefillChunkDescriptor(NamedTuple):
     tokens point at the scratch block 0. All integer arrays are int32.
     """
     tokens: jnp.ndarray          # (B, C)  prompt token ids (zero-padded)
-    start_pos: jnp.ndarray       # (B,)    absolute position of tokens[b, 0]
+    start_pos: jnp.ndarray       # (B,)    absolute position of tokens[r, 0]
     n_valid: jnp.ndarray         # (B,)    valid tokens in this chunk (<= C)
     block_table: jnp.ndarray     # (B, NB) window blocks covering [wb, start)
     window_base: jnp.ndarray     # (B,)    absolute pos of table[b,0] token 0
@@ -241,6 +246,37 @@ def _chunk_layout(batch: int, chunk: int, n_blocks: int):
 
 def chunk_flat_size(batch: int, chunk: int, n_blocks: int) -> int:
     return _chunk_layout(batch, chunk, n_blocks)[1]
+
+
+def chunk_flat_rows(size: int, chunk: int, n_blocks: int) -> int:
+    """Rows of a flat chunk descriptor of ``size`` int32 words."""
+    per_row = chunk_flat_size(1, chunk, n_blocks)
+    if size % per_row:
+        raise ValueError(f"{size} words is not a whole number of chunk rows "
+                         f"of {per_row}")
+    return size // per_row
+
+
+def chunk_row_widths(batch: int) -> tuple:
+    """The row widths the chunk executor is compiled at: the powers of 4
+    from 4 below ``batch``, then ``batch`` itself (48 -> 4, 16, 48)."""
+    widths, w = [], 4
+    while w < batch:
+        widths.append(w)
+        w *= 4
+    return tuple(widths) + (batch,)
+
+
+def chunk_row_width(widths, rows: int) -> int:
+    """The smallest of ``widths`` that holds ``rows`` rows (ValueError when
+    none does)."""
+    return min(w for w in widths if w >= rows)
+
+
+def pack_chunk_rows(cd: PrefillChunkDescriptor, width: int) -> np.ndarray:
+    """The flat descriptor of the first ``width`` rows of the host views
+    ``cd`` (field-major, as ``unflatten_chunk_descriptor`` reads it)."""
+    return np.concatenate([f[:width].ravel() for f in cd])
 
 
 def flat_chunk_views(flat: np.ndarray, batch: int, chunk: int,

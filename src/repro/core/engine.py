@@ -30,9 +30,11 @@ Hot-path structure (DESIGN.md §3):
   * ``pipeline_depth = 0`` preserves the exact seed behavior (per-slot
     descriptor assembly, blocking readback each step) for A/B measurement.
   * ``prefill_chunk = C > 0`` ingests prompts through a second fixed-shape
-    chunked prefill executor (compiled once) at C tokens per engine step
-    instead of one; the final prompt token always goes through the decode
-    step so sampled-token semantics are unchanged.
+    chunked prefill executor at C tokens per engine step instead of one;
+    the slots with chunk work are packed into its first rows and it runs at
+    the smallest of a few row widths derived from B (each compiled once, all
+    when the engine is built). The final prompt token always goes
+    through the decode step so sampled-token semantics are unchanged.
   * ``prefix_cache`` (DESIGN.md §9) indexes committed full prompt blocks
     in a radix tree keyed on token-id block chunks; admissions that match
     COW-alias the cached chain (unaligned tails get an audited device-side
@@ -62,11 +64,13 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import spans
 from repro.core.descriptor import (FrameDescriptor, active_block_extents,
-                                   chunk_flat_size, control_plane_size,
-                                   control_plane_views,
+                                   chunk_flat_rows, chunk_flat_size,
+                                   chunk_row_width, chunk_row_widths,
+                                   control_plane_size, control_plane_views,
                                    descriptor_flat_size,
                                    empty_descriptor, flat_chunk_views,
-                                   flat_descriptor_views, refresh_control_row,
+                                   flat_descriptor_views, pack_chunk_rows,
+                                   refresh_control_row,
                                    unflatten_chunk_descriptor,
                                    unflatten_descriptor)
 from repro.core.farview import FarViewPolicy
@@ -429,29 +433,37 @@ class KVRMEngine:
         self._step_sig = None
         self.debug_logits: List[np.ndarray] = []
 
-        # --- chunked prefill executor (second fixed-shape compilation) ---
+        # --- chunked prefill executor (one compilation per row width) ---
         self._chunked = (ecfg.prefill_chunk > 0
                          and registry.supports_chunked_prefill(cfg)
                          and not self.farview)
         self.chunk = int(ecfg.prefill_chunk) if self._chunked else 0
         if self._chunked:
-            CD = chunk_flat_size(B, self.chunk, self.NB)
             C = self.chunk
+            # the row width is the flat operand's length: jit keeps one
+            # executable per width, all compiled at the end of __init__
             def _chunk_step(params, pools, cflat):
-                cdescr = unflatten_chunk_descriptor(cflat, B, C, NB)
+                rows = chunk_flat_rows(cflat.shape[0], C, NB)
+                cdescr = unflatten_chunk_descriptor(cflat, rows, C, NB)
                 with ops.kernel_mesh(self.mesh):
                     return registry.prefill_chunk(params, cfg_dec, pools,
                                                   cdescr)
             ckw = ({} if self.mesh is None else dict(
                 in_shardings=(self._param_sh, PS, R), out_shardings=PS))
-            self._chunk_fn = jax.jit(_chunk_step, donate_argnums=(1,), **ckw)
-            self._cflat = np.zeros(CD, np.int32)
-            self._cdescr = flat_chunk_views(self._cflat, B, self.chunk, self.NB)
+            # _chunk_fn is the one callable every chunk dispatch goes
+            # through; _chunk_jit stays the jitted function (compile, audit)
+            self._chunk_jit = jax.jit(_chunk_step, donate_argnums=(1,), **ckw)
+            self._chunk_fn = self._chunk_jit
+            self._chunk_widths = chunk_row_widths(B)
+            # host views: B rows, this step's live rows packed first
+            self._cflat = np.zeros(chunk_flat_size(B, C, NB), np.int32)
+            self._cdescr = flat_chunk_views(self._cflat, B, C, NB)
         else:
-            self._chunk_fn = None
+            self._chunk_fn = self._chunk_jit = None
         # below this many remaining prompt tokens, ingestion rides the decode
-        # step instead (zero marginal steps while other slots decode) — a
-        # full-width batched chunk call isn't worth it for a tiny remainder.
+        # step instead (zero marginal steps while other slots decode) — even
+        # a narrow chunk call pays a fixed cost per call (the weights read,
+        # the layer scan's pool slicing) that a tiny remainder doesn't earn.
         # Capped at a few blocks so an oversized C never disables chunking.
         self._chunk_min = (max(self.bt, min(self.chunk // 2, 4 * self.bt))
                            if self._chunked else 0)
@@ -568,6 +580,9 @@ class KVRMEngine:
                                          cv, pools["cross_v"]),
                     "enc_len": jnp.where(slot_onehot, elen, pools["enc_len"]),
                 })
+        # set-up, not the first request with prompt work, pays these
+        if self._chunked:
+            self._compile_chunk_widths()
 
     # ------------------------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -1326,25 +1341,23 @@ class KVRMEngine:
     def _prefill_chunks(self) -> None:
         """Ingest up to ``prefill_chunk`` prompt tokens per prefilling slot
         through the batched chunked prefill executor: ONE dispatch per engine
-        step covering every slot with chunk work (idle slot rows are masked
-        by n_valid=0, same fixed-shape discipline as the decode step).
+        step covering every slot with chunk work. Each such slot takes the
+        next free row, in slot order; the call runs at the smallest compiled
+        row width that holds them, its other rows masked by n_valid=0.
         Reservations are sealed by THIS step's single frame commit."""
         C = self.chunk
         cd = self._cdescr
         self._chunk_wait = 0.0
-        any_chunk = False
         with spans.Span(spans.PREFILL_ASSEMBLE) as sp:
             rows = tokens = 0
             for slot in self.sched.active_slots():
                 if self.sched.chunk_remaining(slot) < self._chunk_min:
                     continue
-                if not any_chunk:
-                    cd.n_valid[:] = 0
-                    any_chunk = True
                 self._step_touched.add(slot)
                 self.sched.request_at(slot).prompt_chunk_steps += 1
                 toks = self.sched.consume_prompt_chunk(slot, C)
                 n = len(toks)
+                r = rows
                 rows += 1
                 tokens += n
                 t0 = int(self._slot_len[slot])
@@ -1361,29 +1374,41 @@ class KVRMEngine:
                 # query; later queries only need a suffix of it (masked
                 # in-kernel)
                 blocks, wb = self._window_blocks(slot)
-                cd.tokens[slot, :n] = toks
-                cd.tokens[slot, n:] = 0
-                cd.start_pos[slot] = t0
-                cd.n_valid[slot] = n
-                cd.block_table[slot] = blocks
-                cd.window_base[slot] = wb
-                cd.write_block[slot, :n] = wblk
-                cd.write_block[slot, n:] = 0
-                cd.write_offset[slot, :n] = woff
-                cd.write_offset[slot, n:] = 0
+                cd.tokens[r, :n] = toks
+                cd.tokens[r, n:] = 0
+                cd.start_pos[r] = t0
+                cd.n_valid[r] = n
+                cd.block_table[r] = blocks
+                cd.window_base[r] = wb
+                cd.write_block[r, :n] = wblk
+                cd.write_block[r, n:] = 0
+                cd.write_offset[r, :n] = woff
+                cd.write_offset[r, n:] = 0
                 self._slot_len[slot] += n
                 self._win_dirty[slot] = True
                 self._chunk_steps += 1
-            sp.stats(rows=rows, tokens=tokens)
-        if any_chunk:
+            cd.n_valid[rows:] = 0
+            width = chunk_row_width(self._chunk_widths, rows) if rows else 0
+            sp.stats(rows=rows, tokens=tokens, row_width=width)
+        if rows:
             with spans.Span(spans.CHUNK_DISPATCH) as dsp:
-                self.pools = self._chunk_fn(self.params, self.pools,
-                                            jnp.asarray(self._cflat.copy()))
+                self.pools = self._chunk_fn(
+                    self.params, self.pools,
+                    jnp.asarray(pack_chunk_rows(cd, width)))
             # dispatch can block on the runtime's in-flight queue while the
             # PREVIOUS step still executes — that wait is device occupancy,
             # not host control work; the pipelined path subtracts it from
             # m.host so submit_share keeps measuring the control plane
             self._chunk_wait = dsp.seconds
+
+    def _compile_chunk_widths(self) -> None:
+        """Compile the chunk executor at every row width, so that no step
+        compiles whichever width it needs. Compiling ahead of time fills
+        the same cache the jitted function's calls look in."""
+        for w in self._chunk_widths:
+            flat = np.zeros(chunk_flat_size(w, self.chunk, self.NB), np.int32)
+            self._chunk_jit.lower(self.params, self.pools,
+                                  jnp.asarray(flat)).compile()
 
     # ------------------------------------------------------------------
     def step(self, now: float = float("inf")) -> StepMetrics:
@@ -1944,8 +1969,8 @@ class KVRMEngine:
         hosts = np.array([m.host for m in steps]) if steps else np.zeros(1)
         commits = np.array([m.frame_commit for m in steps]) if steps else np.zeros(1)
         ncomp = getattr(self._step_fn, "_cache_size", lambda: -1)()
-        nc_prefill = (getattr(self._chunk_fn, "_cache_size", lambda: -1)()
-                      if self._chunk_fn is not None else 0)
+        nc_prefill = (getattr(self._chunk_jit, "_cache_size", lambda: -1)()
+                      if self._chunk_jit is not None else 0)
         # field-per-counter typed report (serving/api.py, §14): a counter
         # added here without an AuditReport field — or vice versa — raises
         # TypeError on every audit call, so the contract cannot drift

@@ -7,8 +7,9 @@ blocks above the diagonal are skipped via masking (TPU grids are static — the
 mask makes the skipped block a no-op; Mosaic elides the copy when the index
 map is revisited). q/k blocks are MXU-aligned (multiples of 128 recommended).
 
-Chunked kernel: grid (B, NB + 1), one C-token chunk per slot. Steps
-0..NB-1 walk the slot's committed near-window block table (scalar prefetch,
+Chunked kernel: grid (R, NB + 1), one C-token chunk per row (the engine
+packs the slots with chunk work into the first rows). Steps
+0..NB-1 walk the row's committed near-window block table (scalar prefetch,
 one ~tau-byte HBM->VMEM copy of a full-KV block per step — the same
 merged-transport contract as the decode kernel) and contract every kv head
 at once; the final step folds the chunk's own K/V causally.

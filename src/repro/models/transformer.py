@@ -84,12 +84,16 @@ def forward(params, cfg: ModelConfig, tokens, *, extra_embeds=None,
 # ---------------------------------------------------------------------------
 
 def prefill_chunk(params, cfg: ModelConfig, pools, descr):
-    """Ingest one C-token prompt chunk PER SLOT under the KV-RM contract.
+    """Ingest one C-token prompt chunk PER ROW under the KV-RM contract.
 
-    descr: PrefillChunkDescriptor (fixed B / C / NB — compiled once, like
-    the decode step; ONE dispatch per engine step with idle slots masked by
-    n_valid=0). Writes each chunk's K/V into the paged pool and returns the
-    updated pools. No logits: the final prompt token always goes through the
+    descr: PrefillChunkDescriptor of R rows x C tokens over NB window
+    blocks. A row is whichever slot the engine packed into it: each row
+    carries its own positions, window table and write targets, and rows
+    with n_valid=0 are masked. The engine compiles this at a few row widths
+    and dispatches the smallest that holds the step's slots with chunk work
+    (ONE dispatch per engine step), so the cost follows the rows passed.
+    Writes each chunk's K/V into the paged pool and returns the updated
+    pools. No logits: the final prompt token always goes through the
     decode step, so sampled-token semantics are unchanged.
     """
     sv = cfg.serving

@@ -20,7 +20,9 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.core.descriptor import chunk_flat_size, empty_descriptor
+from repro.core.descriptor import (chunk_flat_rows, chunk_flat_size,
+                                   chunk_row_widths, empty_descriptor,
+                                   unflatten_chunk_descriptor)
 from repro.kernels.farview_summarize import farview_summarize_pallas
 from repro.kernels.paged_attention import paged_decode_attention_pallas
 from repro.kernels.prefill_attention import chunked_prefill_attention_pallas
@@ -177,7 +179,6 @@ def test_served_decode_step_compiles(one_chip):
 
 
 def test_served_prefill_chunk_compiles(one_chip):
-    from repro.core.descriptor import unflatten_chunk_descriptor
     cfg = get_config("qwen2.5-32b").replace(n_layers=4)
     batch, max_seq, bt, chunk = 8, 2048, 16, 128
     nb, params, pools = _served_shapes(cfg, one_chip, batch=batch,
@@ -193,6 +194,35 @@ def test_served_prefill_chunk_compiles(one_chip):
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, pools, cflat).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", chunk_row_widths(48))
+@pytest.mark.parametrize("arch,chunk", [("qwen2.5-32b", 128), ("yi-34b", 64)])
+def test_served_chunk_executor_compiles_at_every_row_width(one_chip, arch,
+                                                           chunk, rows):
+    """The benchmark cells' chunk executor (4 published-width layers, 48
+    slots x 8192 positions, 16-token blocks) at each row width the engine
+    compiles: the row width is the flat operand's length, as in the
+    engine; the kernel is in it and the program fits one v5e."""
+    cfg = get_config(arch).replace(n_layers=4)
+    batch, max_seq, bt = 48, 8192, 16
+    nb, params, pools = _served_shapes(cfg, one_chip, batch=batch,
+                                       max_seq=max_seq, bt=bt)
+    cfg_dec = cfg.replace(serving=dataclasses.replace(cfg.serving,
+                                                near_window=max_seq))
+
+    def _chunk_step(params, pools, cflat):
+        n = chunk_flat_rows(cflat.shape[0], chunk, nb)
+        cdescr = unflatten_chunk_descriptor(cflat, n, chunk, nb)
+        return registry.prefill_chunk(params, cfg_dec, pools, cdescr)
+
+    cflat = _sds((chunk_flat_size(rows, chunk, nb),), jnp.int32, one_chip)
+    compiled = jax.jit(_chunk_step, donate_argnums=(1,)).lower(
+        params, pools, cflat).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.5e9
 
 
 def test_tp4_decode_step_compiles(topo):
