@@ -4,8 +4,10 @@ window produced against the float32 reference.
 
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file of its own, found by the name ``BENCHMARK.json`` gives it:
-``configs/<config>.json``, ``traffic/<mix>.json``, ``checks/<cell>.json``
-and ``metrics/<metric>.py``.
+``configs/<config>.json``, ``traffic/<mix>.json``, ``checks/<cell>.json``,
+``metrics/<metric>.py`` and ``archs/<name>.py``: the configuration's
+architecture, which makes its weights, its reference, its operation counts
+and the names of its kernels (``archs/__init__.py``).
 """
 from __future__ import annotations
 
@@ -22,26 +24,11 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import loadgen, reference, stats, tracereduce, weights
+from bench import archs, loadgen, stats, tracereduce
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 
-# published config key -> (ModelConfig field, value map)
-HF_KEYS = {
-    "num_hidden_layers": ("n_layers", int),
-    "hidden_size": ("d_model", int),
-    "num_attention_heads": ("n_heads", int),
-    "num_key_value_heads": ("n_kv_heads", int),
-    "head_dim": ("head_dim", int),
-    "intermediate_size": ("d_ff", int),
-    "vocab_size": ("vocab_size", int),
-    "attention_bias": ("qkv_bias", bool),
-    "rope_theta": ("rope_theta", float),
-    "rms_norm_eps": ("norm_eps", float),
-    "tie_word_embeddings": ("tie_embeddings", bool),
-    "hidden_act": ("mlp_act", {"silu": "swiglu"}.__getitem__),
-}
 PROBE_RID = 10 ** 9
 REF_ROWS = 3        # packed rows of max_seq tokens the reference runs over
 
@@ -63,6 +50,11 @@ class Cell:
     @property
     def mc(self) -> dict:
         return self.cfile["model"]
+
+    @property
+    def arch(self):
+        """The architecture module the configuration file names."""
+        return archs.for_config(self.cfile)
 
 
 def _reports(metric: dict, cell: str) -> bool:
@@ -97,11 +89,45 @@ def max_context(cfile: dict) -> int:
 
 
 def model_config(cfile: dict):
-    """The program's ModelConfig for a config file: the architecture's
-    published config with every key of the file applied."""
-    from repro.configs import get_config
-    kw = {fld: conv(cfile["model"][k]) for k, (fld, conv) in HF_KEYS.items()}
-    return get_config(cfile["arch"]).replace(**kw)
+    """The program's ModelConfig for a config file, by its architecture
+    module."""
+    return archs.for_config(cfile).program_config(cfile)
+
+
+def make_weights(cell: Cell, seed: int):
+    """The cell's parameter tree from the seed, by its architecture module,
+    made where the engine will keep it: on the default device for an
+    engine on one device; for a wider ``mesh_spec``, in the program's own
+    parameter shardings on the first lane's mesh (the mesh
+    ``serving.build`` makes for it), so that no device holds the whole
+    tree."""
+    import jax
+
+    from repro.distributed import sharding as shd
+    from repro.launch.mesh import lane_meshes_for_spec
+    arch = cell.arch
+    mesh = lane_meshes_for_spec(
+        cell.cfile["engine"].get("mesh_spec", "1x1"))[0]
+    if mesh is None:
+        return arch.make_params(seed, cell.mc)
+    shapes = jax.eval_shape(lambda: arch.make_params(seed, cell.mc))
+    specs = shd.param_specs(arch.program_config(cell.cfile), shapes)
+    return arch.make_params(
+        seed, cell.mc,
+        shd.to_shardings(mesh, shd.sanitize_specs(mesh, shapes, specs)))
+
+
+def check_placed(params, eng) -> None:
+    """Raise where the engine holds other arrays than the tree it was
+    handed: it placed the weights again (a sharded tree gathered whole, or
+    a copy beside the one made)."""
+    import jax
+    made = jax.tree_util.tree_leaves_with_path(params)
+    held = jax.tree.leaves(eng.params)
+    moved = [jax.tree_util.keystr(p) for (p, a), b in zip(made, held)
+             if a is not b]
+    if len(made) != len(held) or moved:
+        raise RuntimeError(f"the engine placed the weights again: {moved}")
 
 
 def open_chips(chips: int, who: str) -> None:
@@ -164,6 +190,10 @@ class Run:
     @property
     def chips(self) -> int:
         return self.cell.chips
+
+    @property
+    def arch(self):
+        return self.cell.arch
 
     def in_window(self, t_perf: float) -> bool:
         t = t_perf - self.clock0
@@ -281,11 +311,9 @@ def hooks_seen(run: Run) -> None:
     if chunk and not ex["prefill"]["calls"]:
         faults.append(f"no jit_{tracereduce.EXECUTORS['prefill']} module "
                       "in the trace")
-    if ex["decode"]["calls"] and not tr["kernels"]["paged_decode_attention"]:
-        faults.append("decode calls but no paged_decode_attention op")
-    if ex["prefill"]["calls"] and \
-            not tr["kernels"]["chunked_prefill_attention"]:
-        faults.append("chunk calls but no chunked_prefill_attention op")
+    for ex_name, kernel in run.arch.KERNELS.items():
+        if ex[ex_name]["calls"] and not tr["kernels"].get(kernel):
+            faults.append(f"{ex_name} calls but no {kernel} op")
     if tr["other_modules_s"] > 0.01 * tr["window_s"]:
         faults.append(f"{tr['other_modules_s']:.3f} s of device modules of "
                       "no known executor")
@@ -511,8 +539,8 @@ def served_gaps(run: Run, seed: int, rows: List[List[Rec]], *,
     for sample in rows:
         seqs = [np.asarray(r.prompt + tuple(r.tokens), np.int32)
                 for r in sample]
-        ref = reference.logit_gaps(seed, run.mc, seqs, row_len,
-                                   control=control)
+        ref = run.arch.logit_gaps(seed, run.mc, seqs, row_len,
+                                  control=control)
         idx = np.concatenate([
             o + len(r.prompt) - 1 + np.arange(len(r.tokens))
             for r, o in zip(sample, ref["offsets"])])
@@ -573,13 +601,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     cfg = model_config(cell.cfile)
     run = Run(cell=cell, peaks=peaks or {})
     run.phases["imports"] = time.perf_counter() - t_start
-    params = weights.make_params(seed, cell.mc)
+    params = make_weights(cell, seed)
     jax.block_until_ready(params)
     run.phases["weights"] = time.perf_counter() - t_start
     eng_kw = dict(cell.cfile["engine"])
     gw = serving.build(cfg, gateway=True, params=params, **eng_kw)
     jax.block_until_ready(gw.engines[0].pools)
     run.phases["build"] = time.perf_counter() - t_start
+    check_placed(params, gw.engines[0])
     del params
     if build_hook is not None:
         build_hook(gw)
@@ -602,7 +631,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     t_trace = time.perf_counter()
     if trace_dir is not None:
-        run.trace = tracereduce.reduce_dir(trace_dir, n_chips=cell.chips)
+        run.trace = tracereduce.reduce_dir(
+            trace_dir, n_chips=cell.chips,
+            kernels=tuple(cell.arch.KERNELS.values()))
         shutil.rmtree(trace_dir, ignore_errors=True)
         hooks_seen(run)
     t_trace = time.perf_counter() - t_trace

@@ -183,14 +183,14 @@ def main(argv=None) -> None:
     extra = {}
     reduce_dir = tracereduce.reduce_dir
 
-    def reduce_both(trace_dir, n_chips=1):
+    def reduce_both(trace_dir, n_chips=1, **kw):
         # read the trace a second time before the run deletes it
         files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
         if files:
             t = time.perf_counter()
             extra.update(reduce_program(load(files[-1]), n_chips) or {})
             extra["reduce_s"] = time.perf_counter() - t
-        return reduce_dir(trace_dir, n_chips)
+        return reduce_dir(trace_dir, n_chips, **kw)
 
     tracereduce.reduce_dir = reduce_both
     work = ROOT / "bench" / "_work"

@@ -102,15 +102,16 @@ def main(argv=None) -> None:
     ap.add_argument("--warmup-s", type=float, default=60.0)
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
-    from bench import harness, weights
+    from bench import harness
     from repro import serving
     cell = harness.load_cell(args.workload)
     harness.open_chips(cell.chips, "sweep")
     if cell.mix["kind"] != "open":
         sys.exit("sweep: only an open-loop mix has a rate")
-    params = weights.make_params(args.seed, cell.mc)
+    params = harness.make_weights(cell, args.seed)
     gw = serving.build(harness.model_config(cell.cfile), gateway=True,
                        params=params, **cell.cfile["engine"])
+    harness.check_placed(params, gw.engines[0])
     del params
     rates = [float(r) for r in args.rates.split(",")]
     asyncio.run(_sweep(gw, cell, rates, args.seconds, args.seed, harness,

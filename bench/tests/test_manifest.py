@@ -6,7 +6,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import harness
+from bench import archs, harness
 
 ROOT = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -96,7 +96,8 @@ def test_config_file_is_the_published_config_but_for_reduced(entry):
     assert sorted(cfile["reduced"]) == sorted(entry["reduced"])
     built = harness.model_config(cfile)
     published = get_config(cfile["arch"])
-    for key, (fld, _) in harness.HF_KEYS.items():
+    hf_keys = archs.for_config(cfile).HF_KEYS
+    for key, (fld, _) in hf_keys.items():
         if key in entry["reduced"]:
             assert cfile["model"][key] == cfile["reduced"][key]["here"]
             assert getattr(published, fld) == cfile["reduced"][key]["published"]
@@ -104,4 +105,4 @@ def test_config_file_is_the_published_config_but_for_reduced(entry):
             assert getattr(built, fld) == getattr(published, fld), key
     changed = {f for f in published.__dataclass_fields__
                if getattr(built, f) != getattr(published, f)}
-    assert changed == {harness.HF_KEYS[k][0] for k in entry["reduced"]}
+    assert changed == {hf_keys[k][0] for k in entry["reduced"]}

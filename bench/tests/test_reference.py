@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import harness, reference, weights
+from bench import reference, weights
+from bench.archs import dense_gqa
 
 SEED = 2**33 + 5
 
@@ -14,7 +15,7 @@ SEED = 2**33 + 5
 def _tiny(arch):
     from repro.configs import get_reduced
     cfg = get_reduced(arch)
-    mc = {k: getattr(cfg, f) for k, (f, _) in harness.HF_KEYS.items()
+    mc = {k: getattr(cfg, f) for k, (f, _) in dense_gqa.HF_KEYS.items()
           if k != "hidden_act"}
     mc["hidden_act"] = "silu"
     return cfg, mc

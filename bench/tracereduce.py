@@ -20,6 +20,8 @@ import re
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
+# the dense decoder's decode and prefill-chunk attention kernels
+# (``archs/dense_gqa.py``); a cell reads those its architecture module names
 KERNELS = ("paged_decode_attention", "chunked_prefill_attention")
 # executor -> the jitted function whose module runs it (``jit_<fn>(<id>)``)
 EXECUTORS = {"decode": "_step_flat", "prefill": "_chunk_step"}
@@ -83,7 +85,8 @@ def _self_times(iv) -> Dict[str, float]:
     return out
 
 
-def reduce(planes: List[dict], n_chips: int = 1) -> Optional[dict]:
+def reduce(planes: List[dict], n_chips: int = 1,
+           kernels: Tuple[str, ...] = KERNELS) -> Optional[dict]:
     spans = sorted(((n, s, e) for p in planes
                     if not DEVICE_PLANE.match(p["name"])
                     for ln in p["lines"] for n, s, e in ln["events"]
@@ -96,7 +99,7 @@ def reduce(planes: List[dict], n_chips: int = 1) -> Optional[dict]:
         return None
     lo, hi = win[0]
     busy = 0.0
-    kernels: Dict[str, float] = {k: 0.0 for k in KERNELS}
+    kernel_s: Dict[str, float] = {k: 0.0 for k in kernels}
     ops: Dict[str, float] = {}
     execs = {k: {"calls": 0, "seconds": 0.0} for k in EXECUTORS}
     other = 0.0                 # modules of no known executor
@@ -111,8 +114,8 @@ def reduce(planes: List[dict], n_chips: int = 1) -> Optional[dict]:
         for name, d in _self_times(iv).items():
             ops[name] = ops.get(name, 0.0) + d
         for name, s, e in iv:
-            if name in kernels:
-                kernels[name] += (e - s) * 1e-9
+            if name in kernel_s:
+                kernel_s[name] += (e - s) * 1e-9
         merged = _union([(s, e) for _, s, e in iv])
         busy += sum(e - s for s, e in merged) * 1e-9
         for n, s, e in lines.get("XLA Modules", []):
@@ -136,7 +139,7 @@ def reduce(planes: List[dict], n_chips: int = 1) -> Optional[dict]:
     return {
         "window_s": (hi - lo) * 1e-9,
         "busy_s": busy / k,
-        "kernels": {n: v / k for n, v in kernels.items()},
+        "kernels": {n: v / k for n, v in kernel_s.items()},
         "executors": {n: {"calls": v["calls"] // k, "seconds": v["seconds"] / k}
                       for n, v in execs.items()},
         "other_modules_s": other / k,
@@ -155,11 +158,12 @@ def _host_activity(spans, starts, t) -> str:
     return "outside bench spans"
 
 
-def reduce_dir(trace_dir, n_chips: int = 1) -> Optional[dict]:
+def reduce_dir(trace_dir, n_chips: int = 1,
+               kernels: Tuple[str, ...] = KERNELS) -> Optional[dict]:
     files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
     if not files:
         return None
-    return reduce(load_planes(files[-1]), n_chips)
+    return reduce(load_planes(files[-1]), n_chips, kernels)
 
 
 def roofline_share(run, kernel: str, costs) -> Optional[float]:
